@@ -38,6 +38,7 @@ from repro.core.ssl_pipeline import PipelineConfig, SSLPipeline
 from repro.distributed import gtc as gtc_lib
 from repro.launch.steps import make_loss_fn
 from repro.models import build_model
+from repro.runtime.cluster import auto_mesh
 from repro.train import (GTC, GTCShardMap, ListSink, TrainBatch, Trainer)
 
 
@@ -46,7 +47,7 @@ def bench_workers(workers, *, model, cfg, batches, updates, lrs, tau):
     if workers == 1:
         strategy = GTC(gcfg, clip=0.0)
     else:
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         strategy = GTCShardMap(gcfg, mesh, clip=0.0)
     sink = ListSink()
     trainer = Trainer(strategy, {"ce": make_loss_fn(model, cfg, "ce")},

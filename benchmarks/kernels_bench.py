@@ -25,12 +25,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from benchmarks.roofline import HBM_BW, PEAK_FLOPS
-except ImportError:                       # run as a script from benchmarks/
-    from roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND
 
-MACHINE_BALANCE = PEAK_FLOPS / HBM_BW     # flops/byte at the roofline ridge
+try:
+    from benchmarks.roofline import peaks
+except ImportError:                       # run as a script from benchmarks/
+    from roofline import peaks
+
+# flops/byte at the roofline ridge of the v5e, the part every kernel here
+# is tiled for; the analytic roofline shares below are against it
+_PEAK = peaks(PRODUCTION_DEVICE_KIND)
+MACHINE_BALANCE = _PEAK.flops / _PEAK.hbm_bw
 
 
 def _pct_roofline(flops: float, bytes_: float) -> float:

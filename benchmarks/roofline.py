@@ -12,7 +12,9 @@ SPMD-partitioned module reports the *per-device* program, so no further
 /chips.  Also reports MODEL_FLOPS = 6*N*D (dense) or 6*N_active*D (MoE)
 and the usefulness ratio MODEL_FLOPS / HLO_FLOPs.
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware: the peaks of the device kind the dry-run record names
+(``device_kind``, the part its meshes stand for), from the ``PEAKS``
+table; a record without one, or a kind not in the table, is an error.
 """
 from __future__ import annotations
 
@@ -21,9 +23,32 @@ import json
 import os
 from dataclasses import dataclass
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops: float            # dense bf16 FLOP/s
+    hbm_bw: float           # HBM bytes/s
+    ici_link_bw: float      # bytes/s per inter-chip link
+
+
+# Keyed by JAX's ``Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16 (393 TOP/s int8), 16 GB of
+# HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over 4 links
+# (~50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a device not in the table is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}: add them to roofline.PEAKS with "
+                       f"their source")
+    return PEAKS[device_kind]
 
 
 def active_params(cfg) -> int:
@@ -170,6 +195,7 @@ class Roofline:
 
 
 def analyze(record: dict, cfg, shape) -> Roofline:
+    peak = peaks(record.get("device_kind"))
     n_dev = record["n_devices"]
     probe = record.get("probe") or {}
     corrected = "flops" in probe
@@ -190,9 +216,9 @@ def analyze(record: dict, cfg, shape) -> Roofline:
     # bytes are pre-fusion operand counts, overcounted by orders of
     # magnitude (probe rows additionally materialize whole-seq attention)
     byts = memory_traffic(cfg, shape, n_dev, record)
-    terms = {"compute": flops / PEAK_FLOPS,
-             "memory": byts / HBM_BW,
-             "collective": wire / ICI_BW}
+    terms = {"compute": flops / peak.flops,
+             "memory": byts / peak.hbm_bw,
+             "collective": wire / peak.ici_link_bw}
     dom = max(terms, key=terms.get)
     return Roofline(
         arch=record["arch"], shape=record["shape"],

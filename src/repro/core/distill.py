@@ -14,6 +14,7 @@ gather+logsumexp inner loop.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -106,7 +107,7 @@ def chunked_ce(h, w_unembed, labels, *, chunk: int = 8192,
 
 def chunked_topk_distill_ce(h, w_unembed, topk_vals, topk_idx, *,
                             chunk: int = 8192, softcap: float = 0.0,
-                            mask=None, use_kernel: bool = False,
+                            mask=None, use_kernel: Optional[bool] = None,
                             interpret=None):
     """Paper §3.2.2 loss: CE between the renormalized top-k teacher
     distribution and the student's full-vocab distribution.
@@ -115,16 +116,18 @@ def chunked_topk_distill_ce(h, w_unembed, topk_vals, topk_idx, *,
     i.e. effectively zero mass).  loss = Σ_i q_i (lse_student - z_i).
 
     ``use_kernel=True`` routes the logsumexp+gather inner loop through
-    ``kernels.sparse_ce`` (Pallas; differentiable via its custom_vjp —
-    the streamed XLA scan below stays the default and the oracle).
-    ``interpret`` follows the kernels/_dispatch convention.
+    ``kernels.sparse_ce`` (Pallas; differentiable via its custom_vjp);
+    ``False`` runs the streamed XLA scan below, the oracle.  ``None``
+    and ``interpret`` follow the kernels/_dispatch convention: the
+    compiled kernel on TPU, the XLA scan elsewhere.
     """
     b, s, d = h.shape
     k = topk_idx.shape[-1]
     hf = h.reshape(b * s, d)
     idx = topk_idx.reshape(b * s, k)
     vals = topk_vals.reshape(b * s, k).astype(jnp.float32)
-    if use_kernel:
+    from repro.kernels._dispatch import auto_use_kernel
+    if auto_use_kernel(use_kernel):
         from repro.kernels.sparse_ce import topk_distill_ce
         return topk_distill_ce(
             hf, w_unembed, vals, idx, softcap=softcap, interpret=interpret,

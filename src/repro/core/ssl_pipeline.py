@@ -193,6 +193,18 @@ class PipelineConfig:
                    epochs_baseline=4, n_sub_epochs=6, labeled_every=2,
                    chunked_until=4)
 
+    @classmethod
+    def paper(cls) -> "PipelineConfig":
+        """The paper's published widths (§2): 5x768 LSTM student and
+        biLSTM teacher, 3,183 senones, 64 log-mel x3 stacked = 192-d
+        features, top-20 targets.  The synthetic corpus keeps ``small``'s
+        size: width is what the chip must see, corpus size only sets how
+        long a run takes."""
+        return cls(n_labeled=128, n_unlabeled=640, n_val=32, n_speakers=32,
+                   n_senones=3183, n_mels=64, n_layers=5, lstm_hidden=768,
+                   topk=20, epochs_baseline=4, n_sub_epochs=6,
+                   labeled_every=2, chunked_until=4)
+
     @property
     def feat_dim(self) -> int:
         return self.n_mels * 3

@@ -23,7 +23,7 @@ from repro.pipeline.generate import generate_corpus
 
 class TeacherRunner:
     def __init__(self, cfg, params, *, k: int = 20, temperature: float = 1.0,
-                 policy=None, topk_impl: str = "lax"):
+                 policy=None, topk_impl=None):
         from repro.serve import THROUGHPUT, StreamingEngine
         self.cfg = cfg
         self.k = k

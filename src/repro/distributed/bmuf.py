@@ -178,8 +178,6 @@ def make_sharded_bmuf_block_step(train_step: Callable, cfg: BMUFConfig,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import shard_map
-
     ax = worker_axes if len(worker_axes) > 1 else worker_axes[0]
 
     from repro.utils.introspect import takes_rng as _takes
@@ -269,11 +267,11 @@ def make_sharded_bmuf_block_step(train_step: Callable, cfg: BMUFConfig,
         if have_act:
             in_specs.append(wspec)
             args.append(jnp.asarray(active, jnp.float32))
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(wspec, wspec, P(None, ax), rspec, rspec),
-            check_rep=False)
+            check_vma=False)
         workers, opt_states, metrics, theta_g, delta = fn(*args)
         return ({"theta_g": theta_g, "delta": delta, "workers": workers},
                 opt_states, metrics)

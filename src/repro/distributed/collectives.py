@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import shard_map
+from repro.runtime.cluster import auto_mesh
 
 tmap = jax.tree_util.tree_map
 
@@ -22,7 +22,7 @@ def worker_mesh(n: int = 0) -> Mesh:
     devs = jax.devices()
     if n:
         devs = devs[:n]
-    return jax.make_mesh((len(devs),), ("worker",), devices=devs)
+    return auto_mesh((len(devs),), ("worker",), devices=devs)
 
 
 def replicate(tree, mesh: Mesh):
@@ -49,8 +49,8 @@ def data_parallel(fn: Callable, mesh: Mesh, axis: str = "worker",
         def body(*sargs):
             return fn(*sargs)
 
-        out = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=P(axis), check_rep=False)(*args)
+        out = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(axis), check_vma=False)(*args)
         return out
     return wrapped
 

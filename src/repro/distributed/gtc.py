@@ -20,10 +20,10 @@ accumulation must widen to int32 (``GTCConfig.int32_accum``) and
 wrapping.
 
 One code path owns the math.  ``compress_tree`` is the error-feedback
-selection (optionally dispatched to the fused Pallas kernel
-``repro.kernels.gtc_compress`` via ``GTCConfig.use_kernel``, with the
-pure-jnp ref as fallback); ``pack_int8`` / ``unpack_int8`` are the only
-pack/unpack pair; ``wire_reduce`` is the wire itself — the same
+selection (the fused Pallas kernel ``repro.kernels.gtc_compress`` on
+TPU, the bitwise-identical pure-jnp ref elsewhere;
+``GTCConfig.use_kernel`` overrides that ``kernels._dispatch`` default);
+``pack_int8`` / ``unpack_int8`` are the only pack/unpack pair; ``wire_reduce`` is the wire itself — the same
 function serves the single-process ``train.GTC`` strategy (a degenerate
 pack/unpack round-trip), ``make_gtc_allreduce`` (inside an existing
 shard_map/pmap), and ``make_sharded_gtc_train_step`` (the
@@ -41,6 +41,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._dispatch import auto_use_kernel
 from repro.kernels.gtc_compress import gtc_compress
 from repro.kernels.gtc_compress.ref import gtc_compress_ref
 
@@ -57,28 +58,30 @@ class GTCConfig:
     int32_accum: bool = False        # widen the psum to int32 (required
                                      # beyond 127 workers; the narrow int8
                                      # wire is exact below that)
-    use_kernel: bool = False         # fused Pallas compression kernel
-                                     # (interpret-mode on CPU) vs the ref
+    use_kernel: Optional[bool] = None  # fused Pallas compression kernel;
+                                       # None: on TPU only (_dispatch)
 
 
 # ----------------------------------------------------------- compression
 
-def compress_leaf(g, r, tau: float, *, use_kernel: bool = False):
+def compress_leaf(g, r, tau: float, *, use_kernel: Optional[bool] = None):
     """One tensor: error-feedback threshold compression.
 
     Returns (send, new_residual); send has values in {-tau, 0, +tau}.
     ``use_kernel`` routes through the fused Pallas pass
-    (``repro.kernels.gtc_compress`` — same math, one HBM round-trip);
-    the default is the pure-jnp reference.  Both are float32 and
-    bitwise-identical.
+    (``repro.kernels.gtc_compress`` — same math, one HBM round-trip)
+    instead of the pure-jnp reference; ``None`` follows
+    ``kernels._dispatch``: the kernel on TPU, the reference elsewhere.
+    Both are float32 and bitwise-identical.
     """
-    if use_kernel:
+    if auto_use_kernel(use_kernel):
         return gtc_compress(g, r, tau)    # auto: compiled on TPU,
                                           # interpret mode elsewhere
     return gtc_compress_ref(jnp.asarray(g), jnp.asarray(r, jnp.float32), tau)
 
 
-def compress_tree(grads, residuals, tau: float, *, use_kernel: bool = False):
+def compress_tree(grads, residuals, tau: float, *,
+                  use_kernel: Optional[bool] = None):
     flat_g, treedef = jax.tree_util.tree_flatten(grads)
     flat_r = treedef.flatten_up_to(residuals)
     sends, ress = [], []
@@ -254,7 +257,6 @@ def make_sharded_gtc_train_step(loss_fn: Callable,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import shard_map
     from repro.utils.introspect import takes_rng as _takes
 
     ax = worker_axes if len(worker_axes) > 1 else worker_axes[0]
@@ -306,12 +308,12 @@ def make_sharded_gtc_train_step(loss_fn: Callable,
     def step(params, opt_state, gtc_state, batches, lr, rng=None):
         lr = jnp.asarray(lr, jnp.float32)
         if rng is None or not takes_rng:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda r, b, p, o, l: shard_body(r, b, p, o, l, None),
                 mesh=mesh,
                 in_specs=(wspec, wspec, rspec, rspec, rspec),
                 out_specs=(rspec, rspec, wspec, wspec),
-                check_rep=False)
+                check_vma=False)
             params, opt_state, res, ms = fn(gtc_state["residual"], batches,
                                             params, opt_state, lr)
         else:
@@ -320,11 +322,11 @@ def make_sharded_gtc_train_step(loss_fn: Callable,
             # changes the streams, and raw key data crosses the boundary
             wkd = jax.vmap(lambda i: jax.random.key_data(
                 jax.random.fold_in(rng, i)))(jnp.arange(cfg.n_workers))
-            fn = shard_map(
+            fn = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(wspec, wspec, rspec, rspec, rspec, wspec),
                 out_specs=(rspec, rspec, wspec, wspec),
-                check_rep=False)
+                check_vma=False)
             params, opt_state, res, ms = fn(gtc_state["residual"], batches,
                                             params, opt_state, lr, wkd)
         return params, opt_state, {"residual": res}, ms
@@ -365,7 +367,7 @@ def simulate_gtc_round(grads_per_worker, residuals_per_worker, tau: float,
     sends = []
     new_res = []
     for g, r in zip(grads_per_worker, residuals_per_worker):
-        s, nr = compress_tree(g, r, tau)
+        s, nr = compress_tree(g, r, tau, use_kernel=False)
         sends.append(s)
         new_res.append(nr)
     if quantize_int8:

@@ -18,9 +18,17 @@ can't drift:
 """
 from __future__ import annotations
 
+import collections
+import re
 from typing import Optional
 
 import jax
+
+# a compiled Mosaic kernel's HLO line: the custom call's target and, in
+# its op metadata, the jitted wrapper that holds the ``pallas_call``
+_KERNEL_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*?jit\((\w+)\)/pallas_call"')
 
 
 def on_tpu() -> bool:
@@ -39,3 +47,11 @@ def auto_use_kernel(use_kernel: Optional[bool] = None) -> bool:
     if use_kernel is None:
         return on_tpu()
     return bool(use_kernel)
+
+
+def compiled_kernels(compiled_text: str) -> collections.Counter:
+    """{kernel wrapper name: number of compiled calls} in the text of a
+    TPU executable (``jitted.lower(...).compile().as_text()``), e.g.
+    ``{"topk_logits_tiles": 1}``.  Empty off TPU, where no kernel is
+    compiled."""
+    return collections.Counter(_KERNEL_CALL.findall(compiled_text))

@@ -39,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -57,20 +58,20 @@ def _rope_rotate(x, cos, sin, hd: int):
     return jnp.concatenate(parts, axis=1)
 
 
-def _kernel(q_ref, kn_ref, vn_ref, ck_ref, cv_ref, pos_ref, cos_ref,
+def _kernel(pos_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref, cos_ref,
             sin_ref, *refs, hd: int, window: int, scale: float,
             softcap: float, rope: bool, write: bool):
     if write:
         o_ref, nk_ref, nv_ref = refs
     else:
         (o_ref,) = refs
-    p = pos_ref[0, 0]
+    p = pos_ref[pl.program_id(0)]                        # SMEM scalar
     s = ck_ref.shape[2]
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, hdp)
     kn = kn_ref[0, 0].astype(jnp.float32)                # (1, hdp)
     if rope:
-        cos = cos_ref[...].astype(jnp.float32)           # (1, hd/2)
-        sin = sin_ref[...].astype(jnp.float32)
+        cos = cos_ref[0].astype(jnp.float32)             # (1, hd/2)
+        sin = sin_ref[0].astype(jnp.float32)
         q = _rope_rotate(q, cos, sin, hd)
         kn = _rope_rotate(kn, cos, sin, hd)
     ck = ck_ref[0, 0]                                    # (S, hdp)
@@ -112,24 +113,28 @@ def decode_attention_tiles(q, k_new, v_new, ck, cv, pos, cos, sin, *,
                            softcap: float, rope: bool, write: bool,
                            interpret: bool = False):
     """q (B,Hkv,G,hdp); k_new/v_new (B,Hkv,1,hdp); ck/cv (B,Hkv,S,hdp);
-    pos (B,1) i32; cos/sin (B, hd/2) f32.  ``hd`` is the real head dim
+    pos (B,) i32; cos/sin (B, 1, hd/2) f32.  ``hd`` is the real head dim
     (lanes past it are padding).  Returns o (B,Hkv,G,hdp) f32 and, when
     ``write``, the updated caches (aliased in-place over ck/cv).
+
+    ``pos`` is scalar-prefetched into SMEM (a (1, 1) VMEM block of a
+    (B, 1) array is not (8, 128)-aligned); cos/sin carry a unit middle
+    axis so each row's block spans the array's last two dims.
     """
     b, hkv, g, hdp = q.shape
     s = ck.shape[2]
     kern = functools.partial(_kernel, hd=hd, window=window, scale=scale,
                              softcap=softcap, rope=rope, write=write)
-    row4 = lambda bi, hi: (bi, hi, 0, 0)
+    row4 = lambda bi, hi, pos_ref: (bi, hi, 0, 0)
+    row3 = lambda bi, hi, pos_ref: (bi, 0, 0)
     in_specs = [
         pl.BlockSpec((1, 1, g, hdp), row4),
         pl.BlockSpec((1, 1, 1, hdp), row4),
         pl.BlockSpec((1, 1, 1, hdp), row4),
         pl.BlockSpec((1, 1, s, hdp), row4),
         pl.BlockSpec((1, 1, s, hdp), row4),
-        pl.BlockSpec((1, 1), lambda bi, hi: (bi, 0)),
-        pl.BlockSpec((1, cos.shape[1]), lambda bi, hi: (bi, 0)),
-        pl.BlockSpec((1, sin.shape[1]), lambda bi, hi: (bi, 0)),
+        pl.BlockSpec((1, 1, cos.shape[2]), row3),
+        pl.BlockSpec((1, 1, sin.shape[2]), row3),
     ]
     out_specs = [pl.BlockSpec((1, 1, g, hdp), row4)]
     out_shape = [jax.ShapeDtypeStruct((b, hkv, g, hdp), jnp.float32)]
@@ -139,14 +144,17 @@ def decode_attention_tiles(q, k_new, v_new, ck, cv, pos, cos, sin, *,
                       pl.BlockSpec((1, 1, s, hdp), row4)]
         out_shape += [jax.ShapeDtypeStruct(ck.shape, ck.dtype),
                       jax.ShapeDtypeStruct(cv.shape, cv.dtype)]
-        aliases = {3: 1, 4: 2}          # ck -> new k, cv -> new v
+        # operand indices count the prefetched pos first: ck is 4, cv 5
+        aliases = {4: 1, 5: 2}          # ck -> new k, cv -> new v
     out = pl.pallas_call(
         kern,
-        grid=(b, hkv),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv),
+            in_specs=in_specs,
+            out_specs=out_specs),
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(q, k_new, v_new, ck, cv, pos, cos, sin)
+    )(pos, q, k_new, v_new, ck, cv, cos, sin)
     return out if write else (out[0],)

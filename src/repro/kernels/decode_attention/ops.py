@@ -64,12 +64,12 @@ def _decode_attention_kernel(q, k_new, v_new, cache_k, cache_v, pos, *,
     cv = _pad_last(cache_v, 128)
     if rope_theta:
         cos, sin = layers.rope_tables(pos, hd, rope_theta)  # (B, hd/2)
-        cos = cos.astype(jnp.float32)
-        sin = sin.astype(jnp.float32)
+        cos = cos.astype(jnp.float32)[:, None]           # (B, 1, hd/2)
+        sin = sin.astype(jnp.float32)[:, None]
     else:
-        cos = sin = jnp.zeros((b, 1), jnp.float32)
+        cos = sin = jnp.zeros((b, 1, 1), jnp.float32)
     out = decode_attention_tiles(
-        qg, kn, vn, ck, cv, pos[:, None].astype(jnp.int32), cos, sin,
+        qg, kn, vn, ck, cv, pos.astype(jnp.int32), cos, sin,
         hd=hd, window=window, scale=float(1.0 / np.sqrt(hd)),
         softcap=softcap, rope=bool(rope_theta), write=write,
         interpret=interpret)

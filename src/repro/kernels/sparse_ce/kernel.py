@@ -15,9 +15,10 @@ upstream for the few archs above that (ops.py notes).  Grid is
 (T/Tt, V/Vt), vocab innermost ("arbitrary" order semantics: scratch
 accumulates across the V dimension; outputs written on the last step).
 
-The gather never leaves VREGs: `gathered = max over tile columns of
-(logits where col == idx)` via a one-hot mask matmul-free select —
-TPU-native replacement for the GPU's per-thread gather.
+The gather never leaves VREGs: for each of the K teacher ids,
+`gathered = sum over tile columns of (logits where col == idx)` — a
+one-hot compare and a lane reduction, the TPU-native replacement for
+the GPU's per-thread gather (Mosaic lowers no lane gather).
 """
 from __future__ import annotations
 
@@ -58,13 +59,18 @@ def _kernel(h_ref, w_ref, idx_ref, lse_ref, g_ref, m_sc, l_sc, g_sc, *,
                  + jnp.exp(logits - m_new).sum(axis=1, keepdims=True))
     m_sc[...] = m_new
 
-    # gather teacher ids that live in this tile: select-by-equality
+    # gather teacher ids that live in this tile: select-by-equality, one
+    # one-hot compare + lane reduction per k (Mosaic has no lane gather);
+    # exactly one column matches, so the sum is the logit bitwise
     idx = idx_ref[...]                                    # (Tt, K)
     loc = idx - base
     k = idx.shape[1]
-    # (Tt, K): for each k, pick logits[t, loc] iff 0 <= loc < v_tile
-    picked = jnp.take_along_axis(logits, jnp.clip(loc, 0, v_tile - 1),
-                                 axis=1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+    picked = jnp.zeros(idx.shape, jnp.float32)
+    for j in range(k):
+        hit = col == loc[:, j:j + 1]
+        pj = jnp.sum(jnp.where(hit, logits, 0.0), axis=1, keepdims=True)
+        picked = jnp.where(kcol == j, pj, picked)
     inside = (loc >= 0) & (loc < v_tile)
     g_sc[...] = jnp.where(inside, picked, g_sc[...])
 

@@ -39,6 +39,7 @@ def _kernel(cv_ref, ci_ref, t_ref, tk_ref, tp_ref, g_ref,
     ci = ci_ref[...]
     r, c = cv.shape
     col = jax.lax.broadcasted_iota(jnp.int32, cv.shape, 1)
+    rank = jax.lax.broadcasted_iota(jnp.int32, (r, k_cap), 1)
 
     def round_(i, carry):
         cv, vals, idx = carry
@@ -47,9 +48,9 @@ def _kernel(cv_ref, ci_ref, t_ref, tk_ref, tp_ref, g_ref,
         a = jnp.min(jnp.where(is_max, col, c), axis=1)    # min position
         one = col == a[:, None]
         vocab = jnp.sum(jnp.where(one, ci, 0), axis=1)
-        vals = jax.lax.dynamic_update_slice(vals, m[:, None], (0, i))
-        idx = jax.lax.dynamic_update_slice(
-            idx, vocab[:, None].astype(jnp.int32), (0, i))
+        # rank i written by select (Mosaic has no lane dynamic_update_slice)
+        vals = jnp.where(rank == i, m[:, None], vals)
+        idx = jnp.where(rank == i, vocab[:, None], idx)
         cv = jnp.where(one, NEG, cv)
         return cv, vals, idx
 
@@ -67,7 +68,6 @@ def _kernel(cv_ref, ci_ref, t_ref, tk_ref, tp_ref, g_ref,
     svals = vals / safe_t
     e = jnp.exp(svals - svals[:, :1])                     # rank 0 = max
     probs = e / e.sum(axis=1, keepdims=True)
-    rank = jax.lax.broadcasted_iota(jnp.int32, (r, k_cap), 1)
     ri = jax.lax.broadcasted_iota(jnp.int32, (k_cap, k_cap), 0)
     rj = jax.lax.broadcasted_iota(jnp.int32, (k_cap, k_cap), 1)
     tri = (ri < rj).astype(jnp.float32)

@@ -37,7 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape, supports
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch import steps as step_lib
 from repro.models import build_model
 from repro.models.api import abstract_params, input_specs
@@ -211,6 +211,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
         "arch": arch, "shape": shape_name,
         "mesh": "multipod" if multi_pod else "pod",
         "status": "ok", "n_devices": int(n_dev),
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "tag": extra_tag,
         "loss_kind": loss_kind if shape.kind == "train" else shape.kind,
         "n_params": param_count(abstract_params(cfg)),

@@ -13,17 +13,24 @@ from __future__ import annotations
 
 import jax
 
+from repro.runtime.cluster import auto_mesh
 from repro.runtime.cluster import worker_mesh  # noqa: F401  (re-export)
+
+
+# the part the production meshes stand for, as JAX's Device.device_kind
+# names it: the dry-run lowers on host devices shaped like a v5e pod and
+# records this kind for the roofline's peak table
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e pod slice: 16x16 = 256 chips per pod; 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has, as a 1D data mesh (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))

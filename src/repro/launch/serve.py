@@ -8,9 +8,18 @@ admission, one host sync per window, SLO tiers).  Bidirectional AMs
 have no streaming form and use ``StreamingEngine``'s batched path.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b
-  PYTHONPATH=src python -m repro.launch.serve --arch lstm-am-7khr
+  PYTHONPATH=src python -m repro.launch.serve --arch lstm-am-7khr --full
+
+``--full`` serves the published config unreduced (5x768 / 3,183
+senones for ``lstm-am-7khr``); the default is the ``reduced()`` smoke
+size.  On a TPU the AM's top-k emission runs the Pallas kernel.
 """
 from __future__ import annotations
+
+from repro.runtime.env import bootstrap_from_env
+bootstrap_from_env()
+# ^ REPRO_* knobs and the compile cache must land in os.environ before
+# the first jax import locks the XLA client config.
 
 import argparse
 import time
@@ -101,9 +110,13 @@ def main(argv=None):
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=8)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config unreduced")
     args = ap.parse_args(argv)
 
-    cfg = reduced(get_arch(args.arch))
+    cfg = get_arch(args.arch)
+    if not args.full:
+        cfg = reduced(cfg)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     if cfg.family == "lstm_am":

@@ -10,6 +10,8 @@ materialized.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -29,7 +31,7 @@ def model_forward(model, cfg, params, batch):
 
 
 def make_loss_fn(model, cfg, loss_kind: str, *, vocab_chunk: int = 8192,
-                 distill_kernel: bool = False):
+                 distill_kernel: Optional[bool] = None):
     # the trailing ``rng`` opts into the Trainer's per-update key folding
     # (repro.train.strategies): today's forwards are deterministic so the
     # key is unused (and DCE'd), but any stochastic regularizer added to
@@ -42,7 +44,8 @@ def make_loss_fn(model, cfg, loss_kind: str, *, vocab_chunk: int = 8192,
         mask = batch.get("mask")
         if loss_kind == "distill_topk":
             # distill_kernel: Pallas sparse_ce inner loop (grad via its
-            # custom_vjp); default stays the streamed-XLA oracle
+            # custom_vjp) vs the streamed-XLA oracle; None: the kernel
+            # on TPU only (kernels._dispatch)
             loss = distill.chunked_topk_distill_ce(
                 h, w, batch["topk_vals"], batch["topk_idx"],
                 chunk=vocab_chunk, softcap=cap, mask=mask,
@@ -75,7 +78,8 @@ def make_loss_fn(model, cfg, loss_kind: str, *, vocab_chunk: int = 8192,
 
 def make_train_step(model, cfg, *, loss_kind: str = "ce",
                     optimizer: str = "momentum", clip: float = 1.0,
-                    vocab_chunk: int = 8192, distill_kernel: bool = False):
+                    vocab_chunk: int = 8192,
+                    distill_kernel: Optional[bool] = None):
     """-> train_step(params, opt_state, batch, lr).
 
     lr is a *traced* argument (not baked into the closure): an LR

@@ -63,7 +63,10 @@ def main(argv=None):
                     choices=["all", "baseline", "teacher", "targets",
                              "student", "smbr"])
     ap.add_argument("--trainer", default="gtc", choices=["gtc", "bmuf"])
-    ap.add_argument("--scale", default="tiny", choices=["tiny", "small"])
+    ap.add_argument("--scale", default="tiny",
+                    choices=["tiny", "small", "paper"],
+                    help="paper: the published 5x768 / 3,183-senone "
+                         "widths (for the chip; synthetic data)")
     ap.add_argument("--smoke", action="store_true",
                     help="LLM-arch reduced-config smoke run")
     ap.add_argument("--steps", type=int, default=4)
@@ -80,7 +83,9 @@ def main(argv=None):
     ap.add_argument("--gen-procs", type=int, default=0,
                     help="target generation as N real OS processes "
                          "racing the shared ledger (0 = in-process; "
-                         "the manifest is bitwise-identical either way)")
+                         "the manifest is bitwise-identical either way; "
+                         "host-CPU runs only: on an accelerator the "
+                         "children would need this process's device)")
     ap.add_argument("--cluster", default="",
                     help="multi-host launch: 'env' (JAX_COORDINATOR_"
                          "ADDRESS/JAX_NUM_PROCESSES/JAX_PROCESS_ID or "
@@ -103,8 +108,7 @@ def main(argv=None):
         return
 
     from repro.core.ssl_pipeline import PipelineConfig, SSLPipeline
-    scale = {"tiny": PipelineConfig.tiny(), "small": PipelineConfig.small()}[
-        args.scale]
+    scale = getattr(PipelineConfig, args.scale)()
     if args.gen_workers is not None:
         scale.gen_workers = args.gen_workers
     if args.gtc_workers is not None:
@@ -112,6 +116,8 @@ def main(argv=None):
     if args.prefetch is not None:
         scale.prefetch = args.prefetch
     if args.gen_procs:
+        from repro.runtime.procs import refuse_children_on_accelerator
+        refuse_children_on_accelerator("--gen-procs")
         scale.gen_procs = args.gen_procs
     pipe = SSLPipeline(scale, out_dir=args.out,
                        student_trainer=args.trainer)

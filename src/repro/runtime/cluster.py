@@ -153,6 +153,19 @@ def widest_divisor(n_workers: int, n_devices: int) -> int:
                if n_workers % d == 0)
 
 
+def auto_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the one mesh
+    rule of this repo.  The installed JAX defaults ``make_mesh`` to
+    ``Explicit`` axes, under which the strategies' scatter-adds and
+    sharding constraints raise ``ShardingTypeError``; every mesh here is
+    built through this function instead."""
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
+
+
 def worker_mesh(n_workers: int, *, axis: str = "data"):
     """The worker-axis mesh for a W-worker shard_map strategy.
 
@@ -164,7 +177,7 @@ def worker_mesh(n_workers: int, *, axis: str = "data"):
     """
     import jax
     n = widest_divisor(n_workers, len(jax.devices()))
-    return jax.make_mesh((n,), (axis,))
+    return auto_mesh((n,), (axis,))
 
 
 # The paper's deployment shapes (§3.4-3.5): name -> worker count.  The

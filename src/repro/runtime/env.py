@@ -151,10 +151,32 @@ def bootstrap(cfg: Optional[EnvConfig] = None, *,
     return cfg
 
 
+COMPILE_CACHE_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: one fixed path per checkout (git-ignored).
+    The path is part of the cache key, so it never carries a temp name,
+    pid or time."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    return os.path.join(root, ".jax_cache")
+
+
 def bootstrap_from_env(environ: Optional[MutableMapping[str, str]] = None
                        ) -> EnvConfig:
-    """``bootstrap(EnvConfig.from_env())`` — the entry-point one-liner."""
-    return bootstrap(EnvConfig.from_env(environ), environ=environ)
+    """``bootstrap(EnvConfig.from_env())`` plus the persistent compile
+    cache — the entry-point one-liner.
+
+    Cache rule: a ``JAX_COMPILATION_CACHE_DIR`` already in the
+    environment is used as it is and no other directory is set;
+    otherwise the cache goes to :func:`default_compile_cache_dir`.  JAX
+    reads the variable when it is imported, so this too must run first.
+    """
+    e = os.environ if environ is None else environ
+    cfg = bootstrap(EnvConfig.from_env(e), environ=environ)
+    e.setdefault(COMPILE_CACHE_VAR, default_compile_cache_dir())
+    return cfg
 
 
 def forced_host_device_count(

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import os
 import signal
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -176,6 +177,25 @@ def repro_pythonpath() -> str:
     if existing and pkg_parent not in existing.split(os.pathsep):
         return pkg_parent + os.pathsep + existing
     return existing or pkg_parent
+
+
+def refuse_children_on_accelerator(what: str):
+    """Raise if this process holds an accelerator backend.
+
+    A chip belongs to one process at a time: a parent that has touched
+    JAX on a TPU (or GPU) holds the device, and a child that needs it
+    then fails or hangs.  So process fleets run on host-CPU backends
+    only; on a chip, work stays in-process.  A parent that never
+    imported JAX holds nothing and passes."""
+    if "jax" not in sys.modules:
+        return
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what}: this process holds the {backend} device, and child "
+            f"processes that need it would fail or hang (one process per "
+            f"chip). Run in-process instead (processes=0, --gen-procs 0).")
 
 
 def child_env(extra: Optional[dict] = None) -> dict:

@@ -45,7 +45,7 @@ import numpy as np
 from repro.pipeline.generate import (WorkLedger, _utt_lens_of,
                                      resolve_engine_factory)
 from repro.runtime import procs
-from repro.runtime.env import bootstrap_from_env
+from repro.runtime.env import EnvConfig, bootstrap
 from repro.store.logit_store import LogitStoreV2
 
 # ---------------------------------------------------------------- job spec
@@ -195,6 +195,7 @@ class Supervisor:
                  max_restarts: Optional[int] = None,
                  claim_timeout_s: Optional[float] = None,
                  python: str = sys.executable):
+        procs.refuse_children_on_accelerator("Supervisor")
         self.spec_path = spec_path
         self.n_procs = n_procs
         self.heartbeat_timeout_s = heartbeat_timeout_s
@@ -560,5 +561,7 @@ class LaneCrashPlan:
 
 
 if __name__ == "__main__":
-    bootstrap_from_env()        # before any jax the engine may import
+    # before any jax the engine may import; no compile cache: workers
+    # are host-CPU processes (a parent on the chip never starts them)
+    bootstrap(EnvConfig.from_env())
     sys.exit(worker_main())

@@ -24,9 +24,10 @@ frame, so batched == sequential to fp tolerance (pinned by
 tests/test_serve_engine.py).
 
 Top-k emission reuses ``kernels/topk_logits`` (the Pallas selection
-kernel) when ``topk_impl="kernel"``; the default "lax" path is the
+kernel) when ``topk_impl="kernel"``; "lax" is the
 ``logit_store.topk_compress`` codec (same output format — shifted bf16
-values + int32 indices).
+values + int32 indices).  The default ``None`` follows
+``kernels._dispatch``: the kernel on TPU, the codec elsewhere.
 """
 from __future__ import annotations
 
@@ -48,16 +49,19 @@ from repro.serve.batcher import (LATENCY, THROUGHPUT, BatchPolicy,
 from repro.serve.request import CompletedRequest, RequestQueue
 
 
-def make_topk_emitter(k: int, impl: str = "lax", *,
+def make_topk_emitter(k: int, impl: Optional[str] = None, *,
                       interpret: Optional[bool] = None):
     """logits (..., V) -> (vals (..., k) bf16 shifted, idx (..., k) i32).
 
     impl="kernel" routes selection through the Pallas tile kernel
     (``kernels/topk_logits``); "lax" uses the logit-store codec.  Both
     produce the LogitStore wire format (max logit shifted to 0, bf16).
-    ``interpret=None`` auto-detects via ``kernels._dispatch``: compiled
-    on TPU, Pallas interpreter everywhere else.
+    ``impl=None`` and ``interpret=None`` auto-detect via
+    ``kernels._dispatch``: the compiled kernel on TPU, the codec
+    elsewhere (an explicit "kernel" off TPU runs the interpreter).
     """
+    if impl is None:
+        impl = "kernel" if _dispatch.auto_use_kernel() else "lax"
     interpret = _dispatch.auto_interpret(interpret)
     if impl == "kernel":
         def emit(logits):
@@ -108,7 +112,7 @@ class StreamingEngine:
 
     def __init__(self, cfg, params, *, k: int = 20, temperature: float = 1.0,
                  policy: BatchPolicy = THROUGHPUT, n_slots: int = 4,
-                 topk_impl: str = "lax",
+                 topk_impl: Optional[str] = None,
                  interpret: Optional[bool] = None):
         self.cfg = cfg
         self.model = build_model(cfg)

@@ -84,7 +84,7 @@ class StreamServer(SlotServer):
     def __init__(self, cfg, params, *, n_slots: int = 4,
                  chunk_frames: int = 16, sync_every: int = 4,
                  k: int = 20, temperature: float = 1.0,
-                 tiers=None, topk_impl: str = "lax",
+                 tiers=None, topk_impl: Optional[str] = None,
                  interpret: Optional[bool] = None,
                  max_frames: int = 256, state_dtype=jnp.float32):
         if not supports_streaming(cfg):

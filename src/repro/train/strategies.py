@@ -166,7 +166,8 @@ class GTC(_SingleWorker):
 
     Single-process form: grads are compressed against the carried
     residual by ``gtc_lib.compress_tree`` (the shared code path — the
-    Pallas kernel behind ``cfg.use_kernel``) and the update ships
+    Pallas kernel on TPU unless ``cfg.use_kernel`` says otherwise) and
+    the update ships
     through ``gtc_lib.wire_reduce``, which at one worker is a
     pack/unpack round-trip (bitwise identity on ternary sends) — so the
     arithmetic is literally the multi-worker wire's.  The accuracy-
@@ -221,6 +222,24 @@ class GTC(_SingleWorker):
                                  step=state.step + 1), metrics
 
         return update
+
+
+def _worker_spec(mesh, worker_axes):
+    """PartitionSpec of a leading W dim sharded over ``worker_axes``."""
+    from jax.sharding import PartitionSpec as P
+    # a worker axis of size 1 canonicalizes to replicated under GSPMD;
+    # placing it that way keeps first-call == steady-state
+    if all(mesh.shape[a] == 1 for a in worker_axes):
+        return P()
+    return P(worker_axes if len(worker_axes) > 1 else worker_axes[0])
+
+
+def _mesh_shardings(mesh, worker_axes):
+    """(replicated, worker-sharded) NamedShardings on ``mesh``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    return (NamedSharding(mesh, P()),
+            NamedSharding(mesh, _worker_spec(mesh, worker_axes)))
 
 
 class GTCShardMap:
@@ -281,8 +300,10 @@ class GTCShardMap:
         if len(self.worker_axes) == 1:
             from repro.runtime.cluster import worker_mesh
             self.mesh = worker_mesh(w_new, axis=self.worker_axes[0])
-        return state.replace(strategy_state=restack_workers(
-            state.strategy_state, w_new, fold=True))
+        # re-placed on the (possibly narrower) new mesh: the restacked
+        # residual still lives on the old mesh's devices
+        return self.place(state.replace(strategy_state=restack_workers(
+            state.strategy_state, w_new, fold=True)))
 
     def place(self, state: TrainState) -> TrainState:
         """Lay a (fresh or resumed) TrainState out on the mesh the way
@@ -290,26 +311,13 @@ class GTCShardMap:
         residuals sharded over the worker axis — so the first update
         compiles the same executable as every later one (the Trainer
         calls this from init_state and after a resume load)."""
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        rep = NamedSharding(self.mesh, P())
-        wrk = NamedSharding(self.mesh, self._wspec())
+        rep, wrk = _mesh_shardings(self.mesh, self.worker_axes)
+        put = jax.device_put
         return state.replace(
-            params=jax.device_put(state.params, rep),
-            opt_state=jax.device_put(state.opt_state, rep),
-            strategy_state=jax.device_put(state.strategy_state, wrk),
-            step=jax.device_put(state.step, rep),
-            rng=jax.device_put(state.rng, rep))
-
-    def _wspec(self):
-        from jax.sharding import PartitionSpec as P
-        # a worker axis of size 1 canonicalizes to replicated under
-        # GSPMD; placing it that way keeps first-call == steady-state
-        if all(self.mesh.shape[a] == 1 for a in self.worker_axes):
-            return P()
-        return P(self.worker_axes if len(self.worker_axes) > 1
-                 else self.worker_axes[0])
+            params=put(state.params, rep),
+            opt_state=put(state.opt_state, rep),
+            strategy_state=put(state.strategy_state, wrk),
+            step=put(state.step, rep), rng=put(state.rng, rep))
 
     def stack(self, group):
         return tmap(lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
@@ -334,8 +342,7 @@ class GTCShardMap:
             worker_axes=self.worker_axes,
             grad_transform=self._grad_transform())
 
-        from jax.sharding import NamedSharding
-        wrk = NamedSharding(self.mesh, self._wspec())
+        _, wrk = _mesh_shardings(self.mesh, self.worker_axes)
 
         def update(state: TrainState, batches, lr):
             rng = jax.random.fold_in(state.rng, state.step)
@@ -455,7 +462,22 @@ class BMUFShardMap(_BMUFBase):
         if len(self.worker_axes) == 1:
             from repro.runtime.cluster import worker_mesh
             self.mesh = worker_mesh(w_new, axis=self.worker_axes[0])
-        return state
+        return self.place(state)
+
+    def place(self, state: TrainState) -> TrainState:
+        """Worker replicas and per-worker optimizer state sharded over
+        the worker axis, the global params and block momentum
+        replicated — one replica per device when W equals the device
+        count."""
+        rep, wrk = _mesh_shardings(self.mesh, self.worker_axes)
+        put = jax.device_put
+        ss = state.strategy_state
+        return state.replace(
+            params=put(state.params, rep),
+            opt_state=put(state.opt_state, wrk),
+            strategy_state={"delta": put(ss["delta"], rep),
+                            "workers": put(ss["workers"], wrk)},
+            step=put(state.step, rep), rng=put(state.rng, rep))
 
     def _block(self, loss_fn):
         step = make_sgd_step(loss_fn, optimizer=self.optimizer,

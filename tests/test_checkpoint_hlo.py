@@ -80,15 +80,16 @@ def test_wire_bytes_factors():
 
 def test_real_lowered_collectives():
     """End-to-end: a psum under shard_map shows up in the parse."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("w",))
+
+    from repro.runtime.cluster import auto_mesh
+    mesh = auto_mesh((1,), ("w",))
 
     def f(x):
         return jax.lax.psum(x, "w")
 
-    sf = shard_map(f, mesh=mesh, in_specs=P("w"), out_specs=P(),
-                   check_rep=False)
+    sf = jax.shard_map(f, mesh=mesh, in_specs=P("w"), out_specs=P(),
+                       check_vma=False)
     txt = jax.jit(sf).lower(jnp.ones((4, 8))).compile().as_text()
     st = hlo.collective_stats(txt)
     # 1-device psum may fold away; just assert the parser doesn't crash

@@ -84,3 +84,21 @@ def test_swa_variant():
     v = get_arch("deepseek-67b+swa")
     assert all(m == "swa" for m in v.mixers())
     assert v.n_layers == 95
+
+
+def test_paper_scale_is_the_published_am():
+    """PipelineConfig.paper() builds exactly the published student and
+    teacher widths of configs/lstm_am_7khr.py (5x768, 3,183 senones,
+    192-d features), top-20 targets."""
+    from repro.configs.lstm_am_7khr import CONFIG, TEACHER
+    from repro.core.ssl_pipeline import PipelineConfig, am_configs
+    pc = PipelineConfig.paper()
+    student, teacher = am_configs(n_layers=pc.n_layers,
+                                  lstm_hidden=pc.lstm_hidden,
+                                  n_senones=pc.n_senones,
+                                  feat_dim=pc.feat_dim)
+    for got, want in ((student, CONFIG), (teacher, TEACHER)):
+        assert got.mixers() == want.mixers()
+        for key in ("lstm_hidden", "n_senones", "vocab_size", "feat_dim"):
+            assert getattr(got, key) == getattr(want, key), key
+    assert pc.topk == 20 and pc.n_mels == 64

@@ -94,6 +94,31 @@ def test_envconfig_from_env_parsing():
     assert neutral == env.EnvConfig()
 
 
+def test_compile_cache_rule():
+    """A JAX_COMPILATION_CACHE_DIR from outside is kept and nothing else
+    is set; otherwise one fixed path inside the checkout, the same on
+    every call (never a temp name, pid or time)."""
+    outside = {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}
+    env.bootstrap_from_env(outside)
+    assert outside == {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}
+    a, b = {}, {}
+    env.bootstrap_from_env(a)
+    env.bootstrap_from_env(b)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert a == b == {"JAX_COMPILATION_CACHE_DIR":
+                      os.path.join(root, ".jax_cache")}
+
+
+def test_auto_mesh_axes_are_auto():
+    """Every mesh of the repo is Auto-typed (the installed jax defaults
+    make_mesh to Explicit, under which the strategies' resharding
+    raises)."""
+    from jax.sharding import AxisType
+    for mesh in (cluster.worker_mesh(2), cluster.auto_mesh((1, 1),
+                                                           ("a", "b"))):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+
+
 def test_forced_host_device_count_unforced():
     assert env.forced_host_device_count({}) == 0
     assert env.forced_host_device_count({"XLA_FLAGS": "--other=1"}) == 0
@@ -395,6 +420,25 @@ def test_processes_requires_engine_spec(tmp_path):
     store = LogitStoreV2(str(tmp_path / "s"), k=K, vocab=V)
     with pytest.raises(ValueError, match="module:function"):
         generate_sharded(lambda w: None, _batches(2), store, processes=2)
+
+
+def test_process_fleet_refused_when_parent_holds_accelerator(
+        tmp_path, monkeypatch):
+    """One process per chip: a parent whose JAX backend is an
+    accelerator must refuse to start generation workers (they would
+    contend for its device) — loudly, before any child exists."""
+    import jax
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen",
+                        lambda *a, **k: spawned.append(a))
+    store = LogitStoreV2(str(tmp_path / "s"), k=K, vocab=V)
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        generate_sharded(PROBE, _batches(2), store, n_workers=2,
+                         engine_kwargs=PROBE_KW, processes=2)
+    assert spawned == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    procs.refuse_children_on_accelerator("host fleet")   # CPU passes
 
 
 def test_save_load_batches_roundtrip(tmp_path):

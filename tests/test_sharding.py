@@ -1,21 +1,19 @@
 """Sharding policy: every param/cache leaf of every arch gets a legal spec
 on the production meshes (divisibility-checked via AbstractMesh — no device
-init needed; built through utils.compat so the ctor-signature churn across
-jax releases stays out of the tests)."""
+init needed)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ASSIGNED, get_arch, reduced
 from repro.distributed import sharding as sh
 from repro.models.api import abstract_params
-from repro.utils.compat import abstract_mesh
 from repro.utils.trees import map_with_path, tree_paths
 
-POD = abstract_mesh((("data", 16), ("model", 16)))
-MULTI = abstract_mesh((("pod", 2), ("data", 16), ("model", 16)))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_specs(cfg, mesh):
@@ -44,7 +42,7 @@ def test_param_specs_divisible(arch, mesh):
 def test_param_specs_degrade_on_tiny_mesh(arch):
     """Reduced configs on a 1-device mesh: everything degrades to
     replicated (or still-divisible) specs, never an error."""
-    tiny = abstract_mesh((("data", 1), ("model", 1)))
+    tiny = AbstractMesh((1, 1), ("data", "model"))
     _check_specs(reduced(get_arch(arch)), tiny)
 
 
