@@ -39,6 +39,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.runtime.procs import file_lock, heartbeat_age
+from repro.utils.tracing import span
 
 
 def shard_ranges(n_items: int, n_workers: int) -> List[Tuple[int, int]]:
@@ -389,8 +390,9 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
 
     Wave selection (both drivers): see :func:`prepare_ledger`.
     """
-    ledger = prepare_ledger(store, len(batches), n_workers,
-                            ledger_path=ledger_path, wave=wave)
+    with span("gen.ledger"):
+        ledger = prepare_ledger(store, len(batches), n_workers,
+                                ledger_path=ledger_path, wave=wave)
     resumed = ledger.n_done > 0
 
     if processes and processes >= 1:
@@ -415,19 +417,26 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
     engines: Dict[int, object] = {}
     n_written = 0
     worker = 0
+    # spans: ``gen.ledger`` around each ledger transition (a fsynced
+    # rewrite), ``gen.forward`` around the engine's dispatch, with the
+    # ``shard`` that pairs it with the store's ``store.append_shard``;
+    # the store commit opens its own ``store.*`` spans
     while True:
-        claim = ledger.claim(f"worker{worker}")
+        with span("gen.ledger"):
+            claim = ledger.claim(f"worker{worker}")
         if claim is None:
             break
         if worker not in engines:
             engines[worker] = make_engine(worker)
         eng = engines[worker]
         for i in range(claim.lo, claim.hi):
-            vals, idx = eng.forward_topk(batches[i])
+            with span("gen.forward", shard=i):
+                vals, idx = eng.forward_topk(batches[i])
             store.append_shard(i, vals, idx, _utt_lens_of(batches[i]),
                                wave=ledger.wave)
             n_written += 1
-        ledger.mark_done(claim)
+        with span("gen.ledger"):
+            ledger.mark_done(claim)
         worker = (worker + 1) % n_workers
     assert ledger.all_done
     return {"n_shards": len(batches), "n_written": n_written,

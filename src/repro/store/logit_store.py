@@ -51,6 +51,7 @@ import numpy as np
 from repro.runtime.procs import file_lock
 from repro.store.manifest import (Manifest, ShardCorruptionError,
                                   ShardEntry, StoreError, file_checksum)
+from repro.utils.tracing import span
 
 _SHARD_DIR = "shards"
 _V1_SHARD_RE = re.compile(r"shard_(\d+)\.npz$")
@@ -107,22 +108,32 @@ class LogitStoreV2:
                            *, wave: int = 0) -> ShardEntry:
         """Stage a shard's data files on disk WITHOUT committing them to
         the manifest — split out so the commit is a separate, atomic
-        step (and so tests can simulate a writer killed in between)."""
-        vals = np.asarray(vals, dtype=np.float32).astype(np.float16)
-        idx = np.asarray(idx, dtype=np.int32)
+        step (and so tests can simulate a writer killed in between).
+
+        Spans: ``store.fetch`` is the wait for device-resident inputs
+        and their copy to the host, ``store.write`` the float16 cast and
+        the three files, ``store.checksum`` the sha256 re-read."""
+        with span("store.fetch"):
+            vals = np.asarray(vals, dtype=np.float32)
+            idx = np.asarray(idx, dtype=np.int32)
         if vals.shape != idx.shape:
             raise ValueError(f"vals {vals.shape} != idx {idx.shape}")
-        lens = np.asarray(utt_lens if utt_lens is not None
-                          else [int(np.prod(vals.shape[:-1]))], np.int32)
         files = self._shard_files(shard_id, wave)
-        np.save(os.path.join(self.root, files["vals"]), vals)
-        np.save(os.path.join(self.root, files["idx"]), idx)
-        np.save(os.path.join(self.root, files["lens"]), lens)
+        with span("store.write"):
+            lens = np.asarray(utt_lens if utt_lens is not None
+                              else [int(np.prod(vals.shape[:-1]))],
+                              np.int32)
+            np.save(os.path.join(self.root, files["vals"]),
+                    vals.astype(np.float16))
+            np.save(os.path.join(self.root, files["idx"]), idx)
+            np.save(os.path.join(self.root, files["lens"]), lens)
+        with span("store.checksum"):
+            checksum = file_checksum(files, self.root)
         return ShardEntry(
             shard_id=shard_id, wave=wave,
             n_frames=int(np.prod(idx.shape[:-1])),
             k=int(idx.shape[-1]), vocab=self.vocab, files=files,
-            checksum=file_checksum(files, self.root), format="v2")
+            checksum=checksum, format="v2")
 
     @property
     def _manifest_lock(self) -> str:
@@ -156,10 +167,18 @@ class LogitStoreV2:
         With ``wave`` above the live entry's, the new shard atomically
         supersedes it (stale files retired after the manifest commit);
         an older wave raises StaleWaveError.
+
+        The write is one ``store.append_shard`` span carrying ``shard``
+        and ``frames`` (valid frames: the sum of ``utt_lens``, else every
+        frame), with the ``store.manifest`` span of the commit inside it.
         """
-        entry = self._write_shard_files(shard_id, vals, idx, utt_lens,
-                                        wave=wave)
-        self._commit(entry)
+        frames = (int(np.sum(utt_lens)) if utt_lens is not None
+                  else int(np.prod(np.shape(idx)[:-1])))
+        with span("store.append_shard", shard=shard_id, frames=frames):
+            entry = self._write_shard_files(shard_id, vals, idx, utt_lens,
+                                            wave=wave)
+            with span("store.manifest"):
+                self._commit(entry)
         return os.path.join(self.root, entry.files["vals"])
 
     # legacy spelling used by v1 call sites (wave 0 append)

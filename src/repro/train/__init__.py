@@ -18,8 +18,7 @@ from repro.pipeline.prefetch import PrefetchingSource
 from repro.train.data import (DataSource, TrainBatch, chain,
                               distill_shard_source, epoch_source,
                               scheduled_source)
-from repro.train.metrics import (JsonlSink, ListSink, MetricsSink,
-                                 TeeSink)
+from repro.train.metrics import JsonlSink, ListSink, MetricsSink
 from repro.train.state import TrainState, restack_workers
 from repro.train.strategies import (GTC, BMUFShardMap, BMUFVmap,
                                     DistributedStrategy, GTCShardMap,
@@ -33,5 +32,5 @@ __all__ = [
     "make_sgd_step", "init_opt", "restack_workers",
     "epoch_source", "distill_shard_source", "scheduled_source", "chain",
     "PrefetchingSource", "Schedule",
-    "MetricsSink", "ListSink", "JsonlSink", "TeeSink",
+    "MetricsSink", "ListSink", "JsonlSink",
 ]

@@ -54,14 +54,3 @@ class JsonlSink:
     def emit(self, step, tag, metrics):
         with open(self.path, "a") as f:
             f.write(json.dumps({"step": step, "tag": tag, **metrics}) + "\n")
-
-
-class TeeSink:
-    """Fan one emit out to several sinks."""
-
-    def __init__(self, *sinks: MetricsSink):
-        self.sinks = sinks
-
-    def emit(self, step, tag, metrics):
-        for s in self.sinks:
-            s.emit(step, tag, metrics)

@@ -51,6 +51,9 @@ from repro.train.data import DataSource, TrainBatch
 from repro.train.metrics import MetricsSink
 from repro.train.state import TrainState
 from repro.train.strategies import DistributedStrategy
+from repro.utils.tracing import span
+
+_END = object()
 
 
 def _shape_sig(data):
@@ -204,7 +207,15 @@ class Trainer:
         need = self.strategy.microbatches
         n_seen = 0
         group, gtag, gsig, glr = [], None, None, None
-        for tb in source:
+        # spans: ``train.source`` is the wait for the source's next batch
+        # (the last one finds it exhausted), ``train.update`` the jitted
+        # update's dispatch
+        batches = iter(source)
+        while True:
+            with span("train.source"):
+                tb = next(batches, _END)
+            if tb is _END:
+                break
             n_seen += 1
             if n_seen <= consumed:          # resume: replay + skip
                 continue
@@ -234,8 +245,9 @@ class Trainer:
             # the host — the update still sees a traced float, so the
             # one-compile-per-(loss kind, shape) property is untouched
             lr = glr(step) if callable(glr) else glr
-            state, metrics = self.updates[gtag](
-                state, batch, jnp.asarray(lr, jnp.float32))
+            with span("train.update"):
+                state, metrics = self.updates[gtag](
+                    state, batch, jnp.asarray(lr, jnp.float32))
             group = []
             consumed = n_seen
             step += 1

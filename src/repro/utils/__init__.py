@@ -1,6 +1,4 @@
-from repro.utils.introspect import takes_rng
-from repro.utils.trees import (map_with_path, param_count, param_bytes,
-                               split_key_like, tree_paths)
-
-__all__ = ["map_with_path", "param_count", "param_bytes", "split_key_like",
-           "takes_rng", "tree_paths"]
+"""Shared helpers, imported by submodule (``repro.utils.trees``,
+``repro.utils.tracing``, ...).  This package imports none of them itself:
+``trees`` imports jax, and ``tracing`` is opened by modules that must stay
+numpy-only at import (``repro.store``, ``repro.pipeline.generate``)."""
