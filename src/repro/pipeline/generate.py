@@ -415,12 +415,11 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
         make_engine = lambda w: factory(w, kw)  # noqa: E731
 
     engines: Dict[int, object] = {}
-    n_written = 0
+    n_written = overlapped = 0
     worker = 0
     # spans: ``gen.ledger`` around each ledger transition (a fsynced
-    # rewrite), ``gen.forward`` around the engine's dispatch, with the
-    # ``shard`` that pairs it with the store's ``store.append_shard``;
-    # the store commit opens its own ``store.*`` spans
+    # rewrite); ``_commit_range`` opens ``gen.forward`` and the store
+    # commit its own ``store.*`` spans
     while True:
         with span("gen.ledger"):
             claim = ledger.claim(f"worker{worker}")
@@ -428,20 +427,45 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
             break
         if worker not in engines:
             engines[worker] = make_engine(worker)
-        eng = engines[worker]
-        for i in range(claim.lo, claim.hi):
-            with span("gen.forward", shard=i):
-                vals, idx = eng.forward_topk(batches[i])
-            store.append_shard(i, vals, idx, _utt_lens_of(batches[i]),
-                               wave=ledger.wave)
-            n_written += 1
+        overlapped += _commit_range(engines[worker], batches, store,
+                                    claim.lo, claim.hi, ledger.wave)
+        n_written += claim.hi - claim.lo
         with span("gen.ledger"):
             ledger.mark_done(claim)
         worker = (worker + 1) % n_workers
     assert ledger.all_done
     return {"n_shards": len(batches), "n_written": n_written,
-            "n_workers": n_workers, "wave": ledger.wave,
-            "resumed": resumed}
+            "overlapped": overlapped, "n_workers": n_workers,
+            "wave": ledger.wave, "resumed": resumed}
+
+
+def _commit_range(eng, batches: Sequence[dict], store, lo: int, hi: int,
+                  wave: int) -> int:
+    """Forward and commit shards [lo, hi), one batch ahead: batch i+1 is
+    dispatched before shard i's commit, so an asynchronous engine runs it
+    (and copies its inputs) while the host fetches, writes and commits
+    shard i.  Commits stay one per shard in ascending order; nothing is
+    dispatched past ``hi``.  If the look-ahead dispatch raises, shard i
+    is committed before the error propagates.  Returns the commits that
+    ran with the next batch dispatched.
+
+    Each dispatch is a ``gen.forward`` span with the ``shard`` that pairs
+    it with its ``store.append_shard`` and ``ahead``: 1 when a previous
+    shard's commit is still pending, else 0."""
+    with span("gen.forward", shard=lo, ahead=0):
+        out = eng.forward_topk(batches[lo])
+    overlapped = 0
+    for i in range(lo, hi):
+        nxt = None
+        try:
+            if i + 1 < hi:
+                with span("gen.forward", shard=i + 1, ahead=1):
+                    nxt = eng.forward_topk(batches[i + 1])
+                overlapped += 1
+        finally:
+            store.append_shard(i, *out, _utt_lens_of(batches[i]), wave=wave)
+        out = nxt
+    return overlapped
 
 
 def generate_corpus(engine, store, utterances, *, shard_offset: int = 0,

@@ -1,5 +1,6 @@
 """Data-plane pipeline (ISSUE 3): sharded target generation over the
 work ledger, and the async prefetching feed's ordering/determinism."""
+import json
 import os
 
 import jax
@@ -187,6 +188,120 @@ def test_generate_sharded_fresh_ledger_respects_live_wave(tmp_path):
                            n_workers=1, ledger_path=lp)
     assert rep["wave"] == 2
     store.verify()
+
+
+# ------------------------------------------- one batch ahead of the commit
+
+def _plain_store(path, batches):
+    """The serial loop: forward then commit, shard by shard."""
+    store = LogitStoreV2(path, k=K, vocab=V)
+    eng = _FakeEngine(0, [])
+    for i, b in enumerate(batches):
+        vals, idx = eng.forward_topk(b)
+        store.append_shard(i, vals, idx, b["mask"].sum(-1).astype(np.int32))
+    return store
+
+
+@pytest.mark.parametrize("n_workers", [1, 2, 3])
+def test_generate_sharded_dispatches_next_batch_before_commit(
+        tmp_path, monkeypatch, n_workers):
+    """Within a range, batch i+1 is dispatched before shard i's commit;
+    no range's first forward comes before the previous range is done."""
+    batches = _batches(7)
+    events = []
+
+    class _Engine(_FakeEngine):
+        def forward_topk(self, batch):
+            events.append(("forward", next(j for j, b in enumerate(batches)
+                                           if b is batch)))
+            return super().forward_topk(batch)
+
+    class _Store(LogitStoreV2):
+        def append_shard(self, shard_id, *a, **kw):
+            events.append(("append", shard_id))
+            return super().append_shard(shard_id, *a, **kw)
+
+    mark_done = WorkLedger.mark_done
+
+    def _mark_done(self, rng):
+        events.append(("done", rng.lo))
+        return mark_done(self, rng)
+
+    monkeypatch.setattr(WorkLedger, "mark_done", _mark_done)
+    store = _Store(str(tmp_path), k=K, vocab=V)
+    generate_sharded(lambda w: _Engine(w, []), batches, store,
+                     n_workers=n_workers)
+    want = []
+    for lo, hi in shard_ranges(7, n_workers):
+        want.append(("forward", lo))
+        for i in range(lo, hi):
+            if i + 1 < hi:
+                want.append(("forward", i + 1))
+            want.append(("append", i))
+        want.append(("done", lo))
+    assert events == want
+
+
+@pytest.mark.parametrize("n_workers", [1, 2])
+def test_generate_sharded_shards_match_serial_loop(tmp_path, n_workers):
+    batches = _batches(5)
+    plain = _plain_store(str(tmp_path / "plain"), batches)
+    store = LogitStoreV2(str(tmp_path / "gen"), k=K, vocab=V)
+    generate_sharded(lambda w: _FakeEngine(w, []), batches, store,
+                     n_workers=n_workers)
+    assert store.shards() == plain.shards() == list(range(5))
+    for j in range(5):
+        assert (store.manifest.entry(j).checksum
+                == plain.manifest.entry(j).checksum)
+
+
+@pytest.mark.parametrize("fail_at, committed, done", [
+    (1, [0], 0),                 # the first range's look-ahead
+    (4, [0, 1, 2, 3], 1),        # the second range's look-ahead
+])
+def test_generate_sharded_failed_lookahead_commits_shard_before(
+        tmp_path, fail_at, committed, done):
+    """A look-ahead forward that raises leaves the shard before it
+    committed and its range claimed; a re-run completes the store."""
+    batches = _batches(6)
+    store = LogitStoreV2(str(tmp_path / "gen"), k=K, vocab=V)
+    lp = os.path.join(tmp_path, "ledger.json")
+
+    class _FailingEngine(_FakeEngine):
+        def forward_topk(self, batch):
+            if batch is batches[fail_at]:
+                raise RuntimeError("forward failed")
+            return super().forward_topk(batch)
+
+    with pytest.raises(RuntimeError, match="forward failed"):
+        generate_sharded(lambda w: _FailingEngine(w, []), batches, store,
+                         n_workers=2, ledger_path=lp)
+    assert store.shards() == committed
+    with open(lp) as f:
+        status = [r["status"] for r in json.load(f)["ranges"]]
+    assert status == ["done"] * done + ["claimed"] + ["pending"] * (1 - done)
+
+    calls = []
+    rep = generate_sharded(lambda w: _FakeEngine(w, calls), batches, store,
+                           n_workers=2, ledger_path=lp)
+    assert len(calls) == rep["n_written"] == 6 - 3 * done
+    assert store.verify() == 6
+    plain = _plain_store(str(tmp_path / "plain"), batches)
+    for j in range(6):
+        assert (store.manifest.entry(j).checksum
+                == plain.manifest.entry(j).checksum)
+
+
+@pytest.mark.parametrize("n, n_workers", [
+    (6, 1), (6, 2), (7, 3), (2, 4), (1, 1)])
+def test_generate_sharded_counts_overlapped_commits(tmp_path, n, n_workers):
+    """Every commit but a range's last runs with the next batch out."""
+    store = LogitStoreV2(str(tmp_path), k=K, vocab=V)
+    rep = generate_sharded(lambda w: _FakeEngine(w, []), _batches(n), store,
+                           n_workers=n_workers)
+    claims = len(shard_ranges(n, n_workers))
+    assert rep["n_written"] == n
+    assert rep["overlapped"] == rep["n_written"] - claims
 
 
 # ------------------------------------------------------- prefetching feed

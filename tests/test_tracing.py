@@ -85,6 +85,14 @@ def test_generate_sharded_spans_one_append_per_shard(tmp_path):
             assert a[1] <= c[1] and c[2] <= a[2]
     forwards = _named(spans, "repro.gen.forward")
     assert [f[3]["shard"] for f in forwards] == list(range(5))
+    # ranges (0, 3) and (3, 5): each batch but a range's first is
+    # dispatched ahead, with the shard before it still to commit, and
+    # that commit begins after the dispatch has ended
+    assert [f[3]["ahead"] for f in forwards] == [0, 1, 1, 0, 1]
+    by_shard = {a[3]["shard"]: a for a in appends}
+    for f in forwards:
+        if f[3]["ahead"]:
+            assert f[2] <= by_shard[f[3]["shard"] - 1][1]
     # prepare, then a claim and a done mark per range, then the last claim
     ledger = _named(spans, "repro.gen.ledger")
     assert len(ledger) == 1 + 2 * 2 + 1
