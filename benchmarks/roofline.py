@@ -63,8 +63,9 @@ def active_params(cfg) -> int:
                 if cfg.mla:
                     m = cfg.mla
                     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-                    layer += d * m.q_lora_rank \
-                        + m.q_lora_rank * cfg.n_heads * qk
+                    layer += (d * m.q_lora_rank
+                              + m.q_lora_rank * cfg.n_heads * qk
+                              if m.q_lora_rank else d * cfg.n_heads * qk)
                     layer += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     layer += m.kv_lora_rank * cfg.n_heads * \
                         (m.qk_nope_head_dim + m.v_head_dim)

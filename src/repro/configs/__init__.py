@@ -1,12 +1,12 @@
 """Architecture registry: ``--arch <id>`` resolves here."""
 from repro.configs.base import (SHAPES, LayerSpec, MLAConfig, ModelConfig,
-                                Segment, ShapeConfig, reduced, supports,
-                                swa_variant)
+                                Segment, ShapeConfig, YaRNConfig, reduced,
+                                supports, swa_variant)
 
-from repro.configs import (chameleon_34b, deepseek_67b, deepseek_v3_671b,
-                           gemma3_27b, h2o_danube3_4b, lstm_am_7khr,
-                           qwen2_5_3b, qwen3_moe_30b_a3b, recurrentgemma_2b,
-                           whisper_medium, xlstm_350m)
+from repro.configs import (chameleon_34b, deepseek_67b, deepseek_v2_lite,
+                           deepseek_v3_671b, gemma3_27b, h2o_danube3_4b,
+                           lstm_am_7khr, qwen2_5_3b, qwen3_moe_30b_a3b,
+                           recurrentgemma_2b, whisper_medium, xlstm_350m)
 
 ARCHS = {
     "recurrentgemma-2b": recurrentgemma_2b.CONFIG,
@@ -18,6 +18,7 @@ ARCHS = {
     "qwen2.5-3b": qwen2_5_3b.CONFIG,
     "chameleon-34b": chameleon_34b.CONFIG,
     "deepseek-v3-671b": deepseek_v3_671b.CONFIG,
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
     "xlstm-350m": xlstm_350m.CONFIG,
     # the paper's own acoustic model
     "lstm-am-7khr": lstm_am_7khr.CONFIG,
@@ -42,4 +43,4 @@ def get_shape(name: str) -> ShapeConfig:
 
 __all__ = ["ARCHS", "ASSIGNED", "SHAPES", "get_arch", "get_shape", "supports",
            "reduced", "swa_variant", "ModelConfig", "ShapeConfig", "LayerSpec",
-           "Segment", "MLAConfig"]
+           "Segment", "MLAConfig", "YaRNConfig"]

@@ -8,6 +8,7 @@ unrolled (used for remainder layers that don't fill a pattern group).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -32,12 +33,42 @@ class Segment:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 Multi-head Latent Attention dims."""
+    """DeepSeek Multi-head Latent Attention dims.  ``q_lora_rank`` 0 means
+    no query LoRA (DeepSeek-V2-Lite's ``q_lora_rank: null``): the query is
+    one plain projection, with no ``q_norm``."""
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512
     qk_rope_head_dim: int = 64
     qk_nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling as DeepSeek-V2 states it (``rope_scaling`` with
+    ``type: yarn``): rope frequencies blended between the original and
+    ``factor``-times-slower ones over the correction range of
+    ``beta_fast`` .. ``beta_slow`` rotations at ``original_max_position``,
+    and the softmax scale multiplied by ``softmax_mscale()**2``."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+    def mscale_ratio(self) -> float:
+        """The cos/sin tables' factor: mscale over mscale_all_dim."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    def softmax_mscale(self) -> float:
+        """The attention softmax scale's factor is this squared."""
+        return self._mscale(self.factor, self.mscale_all_dim)
 
 
 @dataclass(frozen=True)
@@ -64,6 +95,7 @@ class ModelConfig:
     act: str = "silu"        # silu | gelu
     pos_emb: str = "rope"    # rope | learned | none
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YaRNConfig] = None   # read by MLA's rope
     qkv_bias: bool = False
     qk_norm: bool = False
     tie_embeddings: bool = False
@@ -75,11 +107,13 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
     moe_renorm_topk: bool = True
-    # MLA (deepseek-v3)
+    # MLA (deepseek-v2 / v3)
     mla: Optional[MLAConfig] = None
+    # dtype the parameters are released in; the serving engine holds them
+    # in it (matmul operands in it, float32 accumulation)
+    param_dtype: str = "float32"
     # recurrent
     lru_width: int = 0               # RG-LRU recurrence width (0 -> d_model)
     conv_width: int = 4              # temporal conv in recurrent blocks
@@ -192,7 +226,8 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     for s in cfg.segments[:2]:
         pat = s.pattern[: max(1, min(2, len(s.pattern)))]
         segs.append(Segment(pat, 1))
-    mla = MLAConfig(q_lora_rank=64, kv_lora_rank=32, qk_rope_head_dim=16,
+    mla = MLAConfig(q_lora_rank=64 if cfg.mla.q_lora_rank else 0,
+                    kv_lora_rank=32, qk_rope_head_dim=16,
                     qk_nope_head_dim=32, v_head_dim=32) if cfg.mla else None
     n_sen = min(cfg.n_senones, 97)
     return cfg.replace(
@@ -209,8 +244,6 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         n_shared_experts=min(cfg.n_shared_experts, 1),
         moe_top_k=min(cfg.moe_top_k, 2),
         moe_d_ff=min(cfg.moe_d_ff, 128),
-        capacity_factor=4.0,     # smoke scale: no capacity drops, so
-                                 # decode == apply exactly (tests rely on it)
         mla=mla,
         lru_width=min(cfg.lru_width, d_model) if cfg.lru_width else 0,
         encoder=EncoderConfig(n_layers=2) if cfg.encoder else None,

@@ -33,10 +33,12 @@ class TeacherRunner:
                                       policy=policy or THROUGHPUT,
                                       topk_impl=topk_impl)
         self.model = self.engine.model
-        self.params = params
+        self.params = self.engine.params      # in cfg.param_dtype
 
     def generate(self, batch):
-        """One pre-formed batch -> (vals (B,S,k) bf16, idx (B,S,k) int32)."""
+        """One pre-formed batch -> (vals (B,S,k) bf16, idx (B,S,k) int32),
+        an engine ``TopK`` whose ``expert_counts`` holds the
+        (n_moe_layers, E) expert token counts of a model with experts."""
         return self.engine.forward_topk(batch)
 
     # the spelling pipeline.generate duck-types on (engine-or-runner)

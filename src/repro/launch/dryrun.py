@@ -169,7 +169,7 @@ def _lower_compile(cfg, shape, mesh, *, loss_kind, vocab_chunk,
                              shard_mode=shard_mode)
     (args, _specs) = args_fn(mesh)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, donate_argnums=(0,) if shape.kind != "train"
                          else (0, 1))
         lowered = jitted.lower(*args)

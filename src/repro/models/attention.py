@@ -74,16 +74,18 @@ def _group(q, hkv):
 
 def flash_full_attention(q, k, v, q_pos, kv_pos, *, causal=True,
                          attn_softcap=0.0, chunk_q=512, chunk_kv=1024,
-                         bias_mask=None):
+                         bias_mask=None, scale=None):
     """Two-level chunked flash attention.
 
     q (B,Hkv,G,Sq,hd); k/v (B,Hkv,Skv,hd); q_pos (Sq,), kv_pos (Skv,).
+    ``scale`` multiplies the scores (default ``1/sqrt(hd)``).
     Returns (B,Hkv,G,Sq,hd).
     """
     b, hkv, g, sq, hd = q.shape
     hdv = v.shape[-1]                 # may differ from hd (e.g. MLA)
     skv = k.shape[2]
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     cq = min(chunk_q, sq)
     ckv = min(chunk_kv, skv)
     # pad seq dims to chunk multiples

@@ -6,6 +6,8 @@ norm/statistics math always runs in float32.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -76,11 +78,39 @@ def mlp_apply(params, x, act: str):
 
 # ---------------------------------------------------------------- rope
 
-def rope_tables(positions, dim: int, theta: float):
-    """positions (...,) int -> cos/sin (..., dim/2) float32."""
-    inv = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+def rope_inv_freq(dim: int, theta: float, yarn=None) -> np.ndarray:
+    """(dim/2,) inverse frequencies.  With a ``YaRNConfig`` they are
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``: pair i blends
+    ``1/theta^(2i/dim)`` (weight w_i) with the same over ``factor``
+    (1 - w_i), w_i = 1 - clip((i - lo) / (hi - lo), 0, 1), where lo / hi are
+    the pairs that turn ``beta_fast`` / ``beta_slow`` times over
+    ``original_max_position`` (floored / ceiled)."""
+    if yarn is None:
+        return 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    inv = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+
+    def corr_dim(rot):
+        return (dim * math.log(yarn.original_max_position
+                               / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    w = 1.0 - ramp
+    return (inv / yarn.factor * (1.0 - w) + inv * w).astype(np.float32)
+
+
+def rope_tables(positions, dim: int, theta: float, yarn=None):
+    """positions (...,) int -> cos/sin (..., dim/2) float32 (times YaRN's
+    ``mscale_ratio`` where one is given)."""
+    inv = rope_inv_freq(dim, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = yarn.mscale_ratio()
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x, cos, sin):

@@ -1,4 +1,4 @@
-"""Multi-head Latent Attention (DeepSeek-V3).
+"""Multi-head Latent Attention (DeepSeek-V2 / V3).
 
 Train/prefill: decompress the latent c_kv into per-head K/V and run standard
 flash attention.  Decode: *absorbed* form — the cache stores only
@@ -24,10 +24,14 @@ def init_mla(key, cfg):
     d, h = cfg.d_model, cfg.n_heads
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 8)
+    if m.q_lora_rank:
+        q = {"w_dq": layers.dense_init(ks[0], d, m.q_lora_rank),
+             "q_norm": layers.norm_init(m.q_lora_rank, "rmsnorm"),
+             "w_uq": layers.dense_init(ks[1], m.q_lora_rank, h * qk_dim)}
+    else:
+        q = {"w_q": layers.dense_init(ks[0], d, h * qk_dim)}
     return {
-        "w_dq": layers.dense_init(ks[0], d, m.q_lora_rank),
-        "q_norm": layers.norm_init(m.q_lora_rank, "rmsnorm"),
-        "w_uq": layers.dense_init(ks[1], m.q_lora_rank, h * qk_dim),
+        **q,
         "w_dkv": layers.dense_init(ks[2], d,
                                    m.kv_lora_rank + m.qk_rope_head_dim),
         "kv_norm": layers.norm_init(m.kv_lora_rank, "rmsnorm"),
@@ -38,19 +42,37 @@ def init_mla(key, cfg):
     }
 
 
+def softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale squared where it is set."""
+    m = cfg.mla
+    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.softmax_mscale() ** 2
+    return scale
+
+
+def _rope(cfg, positions):
+    return layers.rope_tables(positions, cfg.mla.qk_rope_head_dim,
+                              cfg.rope_theta, cfg.rope_scaling)
+
+
 def _queries(params, cfg, x, positions):
-    """-> q_nope (B,H,S,nope), q_rope (B,H,S,rope) with rope applied."""
+    """-> q_nope (B,H,S,nope), q_rope (B,H,S,rope) with rope applied.
+    With a query LoRA: x @ w_dq -> q_norm -> @ w_uq; without: x @ w_q."""
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
-    cq = x @ params["w_dq"].astype(x.dtype)
-    cq = layers.norm_apply(params["q_norm"], cq, "rmsnorm")
-    q = (cq @ params["w_uq"].astype(x.dtype)).reshape(
-        b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim).transpose(0, 2, 1, 3)
+    if m.q_lora_rank:
+        cq = x @ params["w_dq"].astype(x.dtype)
+        cq = layers.norm_apply(params["q_norm"], cq, "rmsnorm")
+        q = cq @ params["w_uq"].astype(x.dtype)
+    else:
+        q = x @ params["w_q"].astype(x.dtype)
+    q = q.reshape(b, s, h, m.qk_nope_head_dim
+                  + m.qk_rope_head_dim).transpose(0, 2, 1, 3)
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = q[..., m.qk_nope_head_dim:]
-    cos, sin = layers.rope_tables(positions, m.qk_rope_head_dim,
-                                  cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     q_rope = layers.apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
@@ -62,8 +84,7 @@ def _latents(params, cfg, x, positions):
     c_kv = layers.norm_apply(params["kv_norm"], dkv[..., :m.kv_lora_rank],
                              "rmsnorm")
     k_rope = dkv[..., m.kv_lora_rank:]
-    cos, sin = layers.rope_tables(positions, m.qk_rope_head_dim,
-                                  cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     k_rope = layers.apply_rope(k_rope, cos, sin)
     return c_kv, k_rope
 
@@ -88,7 +109,8 @@ def mla_apply(params, cfg, x, positions):
     cq = s if cfg.attn_whole_seq else 512
     ckv = s if cfg.attn_whole_seq else 1024
     o = attn_mod.flash_full_attention(q[:, :, None], k, v, positions,
-                                      positions, chunk_q=cq, chunk_kv=ckv)
+                                      positions, chunk_q=cq, chunk_kv=ckv,
+                                      scale=softmax_scale(cfg))
     o = o[:, :, 0].transpose(0, 2, 1, 3).reshape(b, s, h * m.v_head_dim)
     return o @ params["wo"].astype(x.dtype)
 
@@ -113,7 +135,7 @@ def mla_decode(params, cfg, x, cache, pos, pages=None):
     m = cfg.mla
     b = x.shape[0]
     h = cfg.n_heads
-    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = softmax_scale(cfg)
     per_row = pos.ndim == 1 and pos.shape[0] == b
     paged = cache["c_kv"].ndim == 2
     if paged and (pages is None or not per_row):

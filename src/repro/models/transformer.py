@@ -128,6 +128,12 @@ def block_decode(params, cfg, spec, x, cache, pos, pages=None,
     return x, cache
 
 
+def _reduce_aux(name, stacked):
+    """A segment's aux, stacked over its layers: the expert token counts
+    stay per layer ((repeat, E)); the losses sum over layers."""
+    return stacked if name.endswith("expert_counts") else jnp.sum(stacked)
+
+
 # --------------------------------------------------------------- the model
 
 class Transformer:
@@ -186,9 +192,10 @@ class Transformer:
         return params["out"]
 
     def unembed(self, params, h):
-        logits = h @ self.unembed_matrix(params).astype(h.dtype)
-        return layers.softcap(logits.astype(jnp.float32),
-                              self.cfg.logit_softcap)
+        """Logits in float32 (float32 accumulation of h's dtype)."""
+        logits = jnp.matmul(h, self.unembed_matrix(params).astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+        return layers.softcap(logits, self.cfg.logit_softcap)
 
     # ---- full-sequence forward ----
     def apply(self, params, tokens, *, embeds=None, positions=None):
@@ -215,21 +222,19 @@ class Transformer:
                 return x, auxs
 
             if cfg.scan_unroll:                     # cost-probe path
-                accs = None
+                per_layer = []
                 for gi in range(seg.repeat):
                     gp = jax.tree_util.tree_map(lambda a: a[gi], seg_params)
                     x, auxs = body(x, gp)
-                    accs = auxs if accs is None else {
-                        k_: accs[k_] + v_ for k_, v_ in auxs.items()}
-                auxs = accs or {}
-                for k_, v_ in auxs.items():
-                    aux_total[f"seg{si}/{k_}"] = jnp.asarray(v_)
-                continue
-            if cfg.remat:
-                body = jax.checkpoint(body)
-            x, auxs = jax.lax.scan(body, x, seg_params)
+                    per_layer.append(auxs)
+                auxs = {k_: jnp.stack([a[k_] for a in per_layer])
+                        for k_ in (per_layer[0] if per_layer else {})}
+            else:
+                if cfg.remat:
+                    body = jax.checkpoint(body)
+                x, auxs = jax.lax.scan(body, x, seg_params)
             for k_, v_ in auxs.items():
-                aux_total[f"seg{si}/{k_}"] = jnp.sum(v_)
+                aux_total[f"seg{si}/{k_}"] = _reduce_aux(k_, v_)
         x = layers.norm_apply(params["final_norm"], x, cfg.norm)
         return x, aux_total
 

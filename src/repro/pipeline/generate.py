@@ -328,7 +328,8 @@ def resolve_engine_factory(spec: str) -> Callable:
     """``"module:function"`` -> the factory callable.  The factory
     contract (process-crossing, so it must be importable by name):
     ``factory(worker_id: int, kwargs: dict) -> engine`` with the engine
-    exposing ``forward_topk(batch) -> (vals, idx)``."""
+    exposing ``forward_topk(batch) -> (vals, idx)`` (a pair that may carry
+    ``expert_counts``, as ``serve.engine.TopK`` does)."""
     mod, _, fn = spec.partition(":")
     if not mod or not fn:
         raise ValueError(f"engine spec {spec!r}: want 'module:function'")
@@ -389,6 +390,10 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
     tuning passthroughs (see ``runtime.workers``).
 
     Wave selection (both drivers): see :func:`prepare_ledger`.
+
+    The in-process report carries ``expert_load`` when the engine returns
+    expert token counts (a model with experts): per MoE layer, the worst
+    over the call's shards of the largest expert's tokens over the mean.
     """
     with span("gen.ledger"):
         ledger = prepare_ledger(store, len(batches), n_workers,
@@ -416,6 +421,7 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
 
     engines: Dict[int, object] = {}
     n_written = overlapped = 0
+    loads: List[np.ndarray] = []
     worker = 0
     # spans: ``gen.ledger`` around each ledger transition (a fsynced
     # rewrite); ``_commit_range`` opens ``gen.forward`` and the store
@@ -428,19 +434,29 @@ def generate_sharded(make_engine: Union[Callable[[int], object], str],
         if worker not in engines:
             engines[worker] = make_engine(worker)
         overlapped += _commit_range(engines[worker], batches, store,
-                                    claim.lo, claim.hi, ledger.wave)
+                                    claim.lo, claim.hi, ledger.wave, loads)
         n_written += claim.hi - claim.lo
         with span("gen.ledger"):
             ledger.mark_done(claim)
         worker = (worker + 1) % n_workers
     assert ledger.all_done
-    return {"n_shards": len(batches), "n_written": n_written,
-            "overlapped": overlapped, "n_workers": n_workers,
-            "wave": ledger.wave, "resumed": resumed}
+    rep = {"n_shards": len(batches), "n_written": n_written,
+           "overlapped": overlapped, "n_workers": n_workers,
+           "wave": ledger.wave, "resumed": resumed}
+    if loads:
+        rep["expert_load"] = np.max(loads, axis=0).tolist()
+    return rep
+
+
+def expert_load(counts) -> np.ndarray:
+    """(n_moe_layers, E) expert token counts -> per layer, the largest
+    expert's count over the mean."""
+    counts = np.asarray(counts, np.float64)
+    return counts.max(axis=-1) / counts.mean(axis=-1)
 
 
 def _commit_range(eng, batches: Sequence[dict], store, lo: int, hi: int,
-                  wave: int) -> int:
+                  wave: int, loads: List[np.ndarray]) -> int:
     """Forward and commit shards [lo, hi), one batch ahead: batch i+1 is
     dispatched before shard i's commit, so an asynchronous engine runs it
     (and copies its inputs) while the host fetches, writes and commits
@@ -451,7 +467,12 @@ def _commit_range(eng, batches: Sequence[dict], store, lo: int, hi: int,
 
     Each dispatch is a ``gen.forward`` span with the ``shard`` that pairs
     it with its ``store.append_shard`` and ``ahead``: 1 when a previous
-    shard's commit is still pending, else 0."""
+    shard's commit is still pending, else 0.
+
+    An engine of a model with experts hands back each batch's expert
+    token counts as its result's ``expert_counts``; they are fetched after
+    the shard's commit (its forward has ended by then) and appended to
+    ``loads`` as ``expert_load``."""
     with span("gen.forward", shard=lo, ahead=0):
         out = eng.forward_topk(batches[lo])
     overlapped = 0
@@ -463,8 +484,12 @@ def _commit_range(eng, batches: Sequence[dict], store, lo: int, hi: int,
                     nxt = eng.forward_topk(batches[i + 1])
                 overlapped += 1
         finally:
-            store.append_shard(i, *out, _utt_lens_of(batches[i]), wave=wave)
-        out = nxt
+            store.append_shard(i, *out, _utt_lens_of(batches[i]),
+                               wave=wave)
+        counts = getattr(out, "expert_counts", None)
+        if counts is not None:
+            loads.append(expert_load(counts))
+        out = nxt                   # shard i's outputs are released here
     return overlapped
 
 

@@ -74,6 +74,43 @@ def make_topk_emitter(k: int, impl: Optional[str] = None, *,
     return lambda logits: ls.topk_compress(logits, k)
 
 
+# float32 logits of one emitter chunk: a batch whose (rows x vocab) logits
+# exceed this runs the head matmul and top-k over row chunks, so the whole
+# batch's logits never exist at once (a 32,768 x 102,400 LM batch is 13.4
+# GB); the AM teacher's 65,536 x 3,183 (0.83 GB) stays one chunk
+LOGIT_CHUNK_BYTES = 1 << 30
+
+
+def logit_chunks(rows: int, vocab: int) -> int:
+    """The fewest row chunks, each an equal share of ``rows``, whose float32
+    logits fit ``LOGIT_CHUNK_BYTES`` (1 when the batch fits)."""
+    for n in range(1, rows + 1):
+        if rows % n == 0 and (rows // n) * vocab * 4 <= LOGIT_CHUNK_BYTES:
+            return n
+    return rows
+
+
+def cast_params(params, dtype: str):
+    """Floating parameters in the model's released dtype (a no-op for
+    float32 models)."""
+    dt = jnp.dtype(dtype)
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dt) if jnp.issubdtype(a.dtype, jnp.floating)
+        and a.dtype != dt else a, params)
+
+
+class TopK(tuple):
+    """One batch's emitted targets, the pair ``(vals, idx)``, carrying
+    ``expert_counts``: the (n_moe_layers, E) expert token counts of a model
+    with experts, else None (never fetched here: they stay where the
+    forward left them)."""
+
+    def __new__(cls, vals, idx, expert_counts=None):
+        out = super().__new__(cls, (vals, idx))
+        out.expert_counts = expert_counts
+        return out
+
+
 class StreamFeed:
     """Handle for a dispatched streaming step: holds the (still
     device-resident) padded outputs plus the chunk map needed to unpad.
@@ -116,7 +153,7 @@ class StreamingEngine:
                  interpret: Optional[bool] = None):
         self.cfg = cfg
         self.model = build_model(cfg)
-        self.params = params
+        self.params = cast_params(params, cfg.param_dtype)
         self.k = k
         self.temperature = temperature
         self.policy = policy
@@ -137,10 +174,12 @@ class StreamingEngine:
         return self._emit(self.model.unembed(params, h) / self.temperature)
 
     def _dict_forward(self, params, batch):
-        """Family-generic forward for pre-formed dict batches (the
-        teacher's legacy surface: works for AM, LM and enc-dec alike).
-        The AM branch adds lens-aware padding; the rest delegates to the
-        train path's dispatch."""
+        """Family-generic forward for pre-formed dict batches, the teacher
+        path: the AMs (``feats``, lens-aware padding), token LMs
+        (``tokens``: dense, MoE, MLA, hybrid, recurrent) and enc-dec
+        (``tokens`` + ``enc_embeds``) through the train path's dispatch.
+        -> (vals, idx, the per-layer expert token counts (n_moe_layers, E)
+        of a model with experts, else None)."""
         if self.cfg.family == "lstm_am":
             lens = batch.get("lens")
             if lens is None and "mask" in batch:
@@ -148,10 +187,26 @@ class StreamingEngine:
                 # without this the biLSTM backward pass would read the
                 # zero padding of partial chunks
                 lens = batch["mask"].sum(axis=-1).astype(jnp.int32)
-            h, _ = self.model.apply(params, batch["feats"], lens=lens)
+            h, aux = self.model.apply(params, batch["feats"], lens=lens)
         else:
-            h, _ = steps.model_forward(self.model, self.cfg, params, batch)
-        return self._emit(self.model.unembed(params, h) / self.temperature)
+            h, aux = steps.model_forward(self.model, self.cfg, params, batch)
+        out = self._emit_rows(params, h)
+        counts = [aux[k_] for k_ in sorted(aux)
+                  if k_.endswith("expert_counts")]
+        return out + (jnp.concatenate(counts) if counts else None,)
+
+    def _emit_rows(self, params, h):
+        """Head + top-k over h (..., D), in ``logit_chunks`` row chunks."""
+        lead = h.shape[:-1]
+        rows = int(np.prod(lead))
+        n = logit_chunks(rows, self.cfg.vocab_size)
+        emit = lambda x: self._emit(  # noqa: E731
+            self.model.unembed(params, x) / self.temperature)
+        if n == 1:
+            return emit(h)
+        vals, idx = jax.lax.map(emit, h.reshape(n, rows // n, h.shape[-1]))
+        return (vals.reshape(lead + vals.shape[-1:]),
+                idx.reshape(lead + idx.shape[-1:]))
 
     def _stream_forward(self, params, state, feats, lens):
         h, new_state = self.model.stream_step(params, state, feats,
@@ -163,9 +218,10 @@ class StreamingEngine:
     # ---------------------------------------------------------- batch path
 
     def forward_topk(self, batch: dict):
-        """One pre-formed batch -> (vals, idx).  No queue, no padding
-        bookkeeping — the thinnest engine surface."""
-        return self._fwd_dict(self.params, batch)
+        """One pre-formed batch -> a ``TopK``: (vals, idx), with the
+        per-layer expert token counts as its ``expert_counts``.  No queue,
+        no padding bookkeeping — the thinnest engine surface."""
+        return TopK(*self._fwd_dict(self.params, batch))
 
     def submit(self, feats: np.ndarray, meta: Optional[dict] = None) -> int:
         """Enqueue one (T, F) utterance; returns its request id.

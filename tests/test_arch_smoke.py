@@ -20,7 +20,8 @@ SMOKE_TRAIN = ShapeConfig("smoke", seq_len=16, global_batch=2, kind="train")
 # representatives of each family stay in the fast lane.
 SLOW_COMPILE = {"recurrentgemma-2b", "deepseek-v3-671b", "whisper-medium",
                 "gemma3-27b", "xlstm-350m", "qwen3-moe-30b-a3b",
-                "chameleon-34b", "deepseek-67b", "h2o-danube-3-4b"}
+                "chameleon-34b", "deepseek-67b", "h2o-danube-3-4b",
+                "deepseek-v2-lite"}
 
 
 def _arch_params(archs):
@@ -218,7 +219,9 @@ def test_whisper_per_row_decode_smoke():
     "qwen2.5-3b",
     pytest.param("xlstm-350m", marks=pytest.mark.slow),
     pytest.param("recurrentgemma-2b", marks=pytest.mark.slow),
-    pytest.param("gemma3-27b", marks=pytest.mark.slow)])
+    pytest.param("gemma3-27b", marks=pytest.mark.slow),
+    # MLA without query LoRA, YaRN, dropless MoE
+    pytest.param("deepseek-v2-lite", marks=pytest.mark.slow)])
 def test_decode_matches_apply(arch):
     """Strong consistency: token-by-token decode logits == full forward."""
     cfg = reduced(get_arch(arch))

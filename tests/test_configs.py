@@ -14,6 +14,7 @@ EXPECTED = {
     "qwen2.5-3b": (36, 2048, 16, 2, 11008, 151936),
     "chameleon-34b": (48, 8192, 64, 8, 22016, 65536),
     "deepseek-v3-671b": (61, 7168, 128, 128, 2048, 129280),
+    "deepseek-v2-lite": (27, 2048, 16, 16, 1408, 102400),
     "xlstm-350m": (24, 1024, 4, 4, 0, 50304),
 }
 
@@ -22,7 +23,8 @@ FAMILY = {
     "deepseek-67b": "dense", "h2o-danube-3-4b": "dense",
     "whisper-medium": "audio", "qwen3-moe-30b-a3b": "moe",
     "qwen2.5-3b": "dense", "chameleon-34b": "vlm",
-    "deepseek-v3-671b": "moe", "xlstm-350m": "ssm",
+    "deepseek-v3-671b": "moe", "deepseek-v2-lite": "moe",
+    "xlstm-350m": "ssm",
 }
 
 
@@ -44,7 +46,7 @@ def test_exact_topology(arch):
 
 
 def test_assignment_complete():
-    assert len(ASSIGNED) == 10
+    assert len(ASSIGNED) == 11
     assert set(EXPECTED) == set(ASSIGNED)
     assert len({FAMILY[a] for a in ASSIGNED}) == 6   # 6 arch types
 
@@ -55,6 +57,13 @@ def test_moe_specs():
     d = get_arch("deepseek-v3-671b")
     assert (d.n_experts, d.moe_top_k, d.n_shared_experts) == (256, 8, 1)
     assert d.mla is not None and d.mtp_depth == 1
+    v2 = get_arch("deepseek-v2-lite")
+    assert (v2.n_experts, v2.moe_top_k, v2.n_shared_experts) == (64, 6, 2)
+    assert (v2.d_ff, v2.moe_renorm_topk, v2.param_dtype) == (
+        10944, False, "bfloat16")
+    assert [s.n_layers for s in v2.segments] == [1, 26]
+    assert v2.mla.q_lora_rank == 0 and v2.mla.kv_lora_rank == 512
+    assert v2.rope_scaling.factor == 40.0
 
 
 @pytest.mark.parametrize("arch", list(EXPECTED))

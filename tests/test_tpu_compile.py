@@ -13,6 +13,7 @@ module that decided at collection time whether its tests exist would
 hand multi-worker pytest runs different test lists.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import pytest
 from repro.kernels._dispatch import compiled_kernels
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.gtc_compress import gtc_compress
+from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.sparse_ce import topk_distill_ce
 from repro.kernels.swa_attention import swa_attention
 from repro.kernels.topk_logits import topk_logits
@@ -132,6 +134,61 @@ def test_swa_attention(spec):
         lambda q, k, v: swa_attention(q, k, v, 1024, interpret=False),
         spec((1, 16, 4096, 128)), spec((1, 2, 4096, 128)),
         spec((1, 2, 4096, 128))), "swa_attention_tiles")
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate-up", "down"])
+def test_moe_gmm_expert_widths(spec, k, n):
+    """DeepSeek-V2-Lite's routed experts on one chip: 64 groups, 32,768
+    positions x 6 experts = 196,608 rows, bf16.  The custom call is the
+    instruction ``gmm.N``, the name a trace's op events carry."""
+    txt = _compiled_text(
+        lambda x, w, g: moe_gmm(x, w, g, use_kernel=True, interpret=False),
+        spec((196_608, k), jnp.bfloat16), spec((64, k, n), jnp.bfloat16),
+        spec((64,), jnp.int32))
+    _assert_kernels(txt, "gmm")
+    assert re.search(r"%gmm(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', txt)
+
+
+def test_moe_expert_parallel_on_a_mesh(topo, monkeypatch):
+    """DeepSeek-V2-Lite's MoE layer at its widths on a described 2x2 v5e
+    mesh (data 2 x model 2), its weights laid out by
+    ``distributed/sharding`` (experts over ``model``): GSPMD cannot
+    partition a Mosaic kernel, so the layer runs expert-parallel in a
+    ``shard_map``.  It compiles with the three ``gmm`` calls, and no
+    all-gather assembles all 64 experts of a weight."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.configs import get_arch
+    from repro.distributed.sharding import batch_spec, tree_param_specs
+    from repro.kernels import _dispatch
+    from repro.models import moe
+    monkeypatch.setattr(_dispatch, "on_tpu", lambda: True)
+    cfg = get_arch("deepseek-v2-lite")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: moe.init_moe(jax.random.PRNGKey(0), cfg)))
+    specs = tree_param_specs({"moe": params}, mesh)["moe"]
+    assert specs["w_gate"][0] == "model", specs
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        params, specs)
+    b, s = 8, 1024
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16,
+                             sharding=NamedSharding(mesh,
+                                                    batch_spec(mesh, b, 2)))
+    with jax.set_mesh(mesh):
+        txt = _compiled_text(lambda p, x: moe.moe_apply(p, cfg, x)[0],
+                             params, x)
+    assert compiled_kernels(txt)["gmm"] == 3, compiled_kernels(txt)
+    e = cfg.n_experts
+    gathers = [ln for ln in txt.splitlines() if "all-gather" in ln
+               and re.search(rf"\[{e},\d+,\d+\]", ln)]
+    assert not gathers, gathers[:2]
 
 
 @pytest.mark.parametrize("kernel", ["topk_logits", "sparse_ce_grad",
