@@ -24,6 +24,9 @@ A strategy exposes:
   n_workers             the *current* worker membership W — a runtime
                         value, not a construction-time constant
   stack(group)          fold that many batches into the update's input
+  places_block          (optional, default False) True when ``stack``
+                        lays the block out in the update's own input
+                        sharding, so its dispatch moves no data
   init_opt(params)      optimizer state (worker-stacked for BMUF)
   init_state(params)    strategy-private state carried in TrainState
   make_update(loss_fn)  (TrainState, batch, lr) -> (TrainState, metrics)
@@ -38,6 +41,7 @@ A strategy exposes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Protocol, runtime_checkable
 
 import jax
@@ -445,8 +449,18 @@ class BMUFVmap(_BMUFBase):
         return bmuf_lib.make_bmuf_block_step(step, self.cfg)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_local(parts, lead):
+    """One device's share of a block: its batches stacked into
+    ``lead`` = (tau, workers on the device) leading dims, on the device
+    that holds them."""
+    return jnp.stack(parts).reshape(lead + parts[0].shape)
+
+
 class BMUFShardMap(_BMUFBase):
     """BMUF with the worker dim sharded over mesh axes (production)."""
+
+    places_block = True
 
     def __init__(self, cfg: bmuf_lib.BMUFConfig, mesh, *,
                  worker_axes=("data",), optimizer: str = "momentum",
@@ -478,6 +492,36 @@ class BMUFShardMap(_BMUFBase):
             strategy_state={"delta": put(ss["delta"], rep),
                             "workers": put(ss["workers"], wrk)},
             step=put(state.step, rep), rng=put(state.rng, rep))
+
+    def stack(self, group):
+        """The block in the update's own input sharding, (tau, W, ...)
+        with W over the worker axes of the mesh held now (after a
+        ``resize``, the new W's): batch j is local step j // W of worker
+        j % W, as ``_BMUFBase.stack`` orders it.  Each device is sent
+        only its own workers' batches, straight from where they are
+        (host memory, or another device for a ``jax.Array``), and stacks
+        them itself; nothing goes through the default device, so the
+        update's dispatch has nothing to reshard, and nothing waits on
+        the device."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        tau, w = self.cfg.block_steps, self.cfg.n_workers
+        sharding = NamedSharding(self.mesh, P(
+            None, *_worker_spec(self.mesh, self.worker_axes)))
+
+        def lay_out(*xs):
+            shape = (tau, w) + tuple(jnp.shape(xs[0]))
+            shards = []
+            for dev, idx in sharding.addressable_devices_indices_map(
+                    shape).items():
+                mine = range(w)[idx[1]]
+                parts = jax.device_put(
+                    [xs[s * w + k] for s in range(tau) for k in mine], dev)
+                shards.append(_stack_local(parts, (tau, len(mine))))
+            return jax.make_array_from_single_device_arrays(
+                shape, sharding, shards)
+
+        return tmap(lay_out, *group)
 
     def _block(self, loss_fn):
         step = make_sgd_step(loss_fn, optimizer=self.optimizer,

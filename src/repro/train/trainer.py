@@ -45,6 +45,7 @@ from typing import Callable, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint import CheckpointStore
 from repro.train.data import DataSource, TrainBatch
@@ -208,8 +209,11 @@ class Trainer:
         n_seen = 0
         group, gtag, gsig, glr = [], None, None, None
         # spans: ``train.source`` is the wait for the source's next batch
-        # (the last one finds it exhausted), ``train.update`` the jitted
-        # update's dispatch
+        # (the last one finds it exhausted), ``train.stack`` the block's
+        # layout (``placed`` 1 where it is laid out in the update's own
+        # input sharding, ``nbytes`` its input bytes), ``train.update``
+        # the jitted update's dispatch
+        placed = int(getattr(self.strategy, "places_block", False))
         batches = iter(source)
         while True:
             with span("train.source"):
@@ -240,14 +244,22 @@ class Trainer:
                 raise KeyError(
                     f"source yielded loss kind {gtag!r} but the Trainer "
                     f"only has {sorted(self.updates)}")
-            batch = self.strategy.stack(group)
+            with span("train.stack", placed=placed,
+                      nbytes=sum(getattr(x, "nbytes", 0) for x in
+                                 jax.tree_util.tree_leaves(group))):
+                batch = self.strategy.stack(group)
             # an LR Schedule is evaluated here, at the update counter, on
             # the host — the update still sees a traced float, so the
-            # one-compile-per-(loss kind, shape) property is untouched
+            # one-compile-per-(loss kind, shape) property is untouched.
+            # Beside a block laid out in the update's own sharding it goes
+            # as a host scalar, which the dispatch sends straight to every
+            # device the update runs on; elsewhere to the default device,
+            # since a host scalar there lets the host queue about twice as
+            # many updates ahead on a TPU, each holding its outputs
             lr = glr(step) if callable(glr) else glr
+            lr = np.float32(lr) if placed else jnp.asarray(lr, jnp.float32)
             with span("train.update"):
-                state, metrics = self.updates[gtag](
-                    state, batch, jnp.asarray(lr, jnp.float32))
+                state, metrics = self.updates[gtag](state, batch, lr)
             group = []
             consumed = n_seen
             step += 1

@@ -12,7 +12,7 @@ import numpy as np
 
 from repro.pipeline import generate_sharded
 from repro.store import LogitStoreV2
-from repro.train import Local, TrainBatch, Trainer
+from repro.train import BMUFShardMap, Local, TrainBatch, Trainer
 
 K, V = 4, 30
 
@@ -113,26 +113,53 @@ def _train_source(n):
     return [TrainBatch(batch, 0.1, "quad") for _ in range(n)]
 
 
+def _strategy(kind):
+    """-> (strategy, batches per update, ``placed`` its stack reports)."""
+    if kind == "local":
+        return Local(clip=0.0), 1, 0
+    from repro.distributed.bmuf import BMUFConfig
+    from repro.runtime.cluster import worker_mesh
+    return BMUFShardMap(BMUFConfig(n_workers=2, block_steps=1),
+                        worker_mesh(2), clip=0.0), 2, 1
+
+
 def test_trainer_fit_spans_per_update_and_per_draw(tmp_path):
     """An exhausted source: one ``train.source`` per batch drawn and one
-    for the draw that finds it empty; with ``max_updates`` the loop stops
-    without drawing again."""
-    tr = Trainer(Local(clip=0.0), {"quad": _quad_loss})
+    for the draw that finds it empty, one ``train.stack`` per update
+    (``placed`` 1 where the strategy lays the block out in the update's
+    sharding, ``nbytes`` the block's input bytes); with ``max_updates``
+    the loop stops without drawing again.  Under ``Local`` and under
+    ``BMUFShardMap``."""
+    for kind in ("local", "bmuf_shard_map"):
+        _check_fit_spans(tmp_path / kind, kind)
+
+
+def _check_fit_spans(tmp_path, kind):
+    strategy, need, placed = _strategy(kind)
+    tr = Trainer(strategy, {"quad": _quad_loss})
     state = tr.init_state({"w": jnp.zeros((D,))})
-    tr.fit(state, _train_source(1), resume=False)          # compile
+    tr.fit(state, _train_source(need), resume=False)       # compile
     spans = _record(tmp_path / "a", lambda: tr.fit(
-        state, _train_source(4), resume=False))
+        state, _train_source(4 * need), resume=False))
     updates = _named(spans, "repro.train.update")
     assert len(updates) == 4
     sources = _named(spans, "repro.train.source")
-    assert len(sources) == 4 + 1
-    for s, u in zip(sources, updates):             # draw, then update
-        assert s[2] <= u[1]
+    assert len(sources) == 4 * need + 1
+    stacks = _named(spans, "repro.train.stack")
+    assert len(stacks) == 4
+    block_bytes = need * sum(x.nbytes
+                             for x in _train_source(1)[0].data.values())
+    for i, (st, u) in enumerate(zip(stacks, updates)):
+        # the block's last draw, then its stack, then its update
+        assert sources[(i + 1) * need - 1][2] <= st[1]
+        assert st[2] <= u[1]
+        assert st[3] == {"placed": placed, "nbytes": block_bytes}
 
     spans = _record(tmp_path / "b", lambda: tr.fit(
-        state, _train_source(6), resume=False, max_updates=3))
+        state, _train_source(6 * need), resume=False, max_updates=3))
     assert len(_named(spans, "repro.train.update")) == 3
-    assert len(_named(spans, "repro.train.source")) == 3
+    assert len(_named(spans, "repro.train.stack")) == 3
+    assert len(_named(spans, "repro.train.source")) == 3 * need
 
 
 def test_numpy_only_modules_import_without_jax():
