@@ -13,9 +13,9 @@ this records what the int8 pack buys as *numbers*:
     density — the sends are identical tensors, only the encoding
     differs; the observed ratio is 4x).
   * **updates/s** at workers ∈ {1, 2, 4} through the same Trainer.fit
-    loop (GTC single-process at W=1, GTCShardMap above), with the lr
-    swept every update — the compile count staying at 1 per strategy is
-    asserted, as in train_bench.
+    loop and the same GTCShardMap on a one-device mesh, with the lr
+    swept every update — the compile count staying at 1 per worker
+    count is asserted, as in train_bench.
   * **gtc_density** — fraction of elements actually nonzero on the
     wire (the sparsity Strom's threshold buys; diagnostic).
 
@@ -39,16 +39,12 @@ from repro.distributed import gtc as gtc_lib
 from repro.launch.steps import make_loss_fn
 from repro.models import build_model
 from repro.runtime.cluster import auto_mesh
-from repro.train import (GTC, GTCShardMap, ListSink, TrainBatch, Trainer)
+from repro.train import GTCShardMap, ListSink, TrainBatch, Trainer
 
 
 def bench_workers(workers, *, model, cfg, batches, updates, lrs, tau):
     gcfg = gtc_lib.GTCConfig(tau=tau, n_workers=workers)
-    if workers == 1:
-        strategy = GTC(gcfg, clip=0.0)
-    else:
-        mesh = auto_mesh((1,), ("data",))
-        strategy = GTCShardMap(gcfg, mesh, clip=0.0)
+    strategy = GTCShardMap(gcfg, auto_mesh((1,), ("data",)), clip=0.0)
     sink = ListSink()
     trainer = Trainer(strategy, {"ce": make_loss_fn(model, cfg, "ce")},
                       metrics=sink)

@@ -1,4 +1,4 @@
-"""Trainer throughput: steps/sec (and frames/sec) for Local vs BMUFVmap.
+"""Trainer throughput: steps/sec (and frames/sec) for Local vs BMUF.
 
 The unified Trainer compiles one lr-as-argument update per (loss kind,
 batch shape); this records what that buys as a *number*:
@@ -28,7 +28,8 @@ from repro.core.ssl_pipeline import PipelineConfig, SSLPipeline
 from repro.distributed.bmuf import BMUFConfig
 from repro.launch.steps import make_loss_fn
 from repro.models import build_model
-from repro.train import BMUFVmap, ListSink, Local, TrainBatch, Trainer
+from repro.runtime.cluster import worker_mesh
+from repro.train import BMUFShardMap, ListSink, Local, TrainBatch, Trainer
 
 
 def bench_strategy(strategy, label, *, model, cfg, batches, updates, lrs):
@@ -96,9 +97,10 @@ def main(argv=None):
         bench_strategy(Local(), "local", model=model, cfg=cfg,
                        batches=batches, updates=args.updates, lrs=lrs),
         bench_strategy(
-            BMUFVmap(BMUFConfig(n_workers=args.workers,
-                                block_steps=args.block_steps)),
-            "bmuf_vmap", model=model, cfg=cfg, batches=batches,
+            BMUFShardMap(BMUFConfig(n_workers=args.workers,
+                                    block_steps=args.block_steps),
+                         worker_mesh(args.workers)),
+            "bmuf", model=model, cfg=cfg, batches=batches,
             updates=args.updates, lrs=lrs),
     ]
     for r in records:

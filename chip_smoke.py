@@ -16,8 +16,8 @@ One chip, all at 5x768 / 3,183 senones / 192-d features / top-20:
 
 ``--chips 4`` runs only the multi-chip path and what it is compared
 with: ``BMUFShardMap`` at W=4 (one worker per chip) against
-``BMUFVmap`` at W=4 on one chip, and ``GTCShardMap``'s W=4 step against
-``simulate_gtc_round``.
+``BMUFShardMap`` at W=4 on a one-chip mesh, and ``GTCShardMap``'s W=4
+step against ``simulate_gtc_round``.
 
 Every phase is checked against a plain float32 reference of the same
 computation, and every jitted step must hold the compiled Pallas kernels
@@ -266,7 +266,8 @@ def phase_student(pipe, batches, store, seed: int):
     from repro.distributed.gtc import GTCConfig, compress_tree
     from repro.launch.steps import make_loss_fn
     from repro.models import build_model
-    from repro.train import (GTC, ListSink, Local, Trainer,
+    from repro.runtime.cluster import worker_mesh
+    from repro.train import (GTCShardMap, ListSink, Local, Trainer,
                              distill_shard_source)
 
     pc, cfg = pipe.pc, pipe.student_cfg
@@ -365,15 +366,17 @@ def phase_student(pipe, batches, store, seed: int):
     n_leaves = len(jax.tree_util.tree_leaves(params))
     out = {}
     for use_kernel in (True, False):
-        gtr = Trainer(GTC(GTCConfig(tau=pc.gtc_tau, n_workers=1,
-                                    use_kernel=use_kernel)),
+        gtr = Trainer(GTCShardMap(GTCConfig(tau=pc.gtc_tau, n_workers=1,
+                                            use_kernel=use_kernel),
+                                  worker_mesh(1)),
                       {"distill_topk": loss_k})
         g0 = gtr.init_state(params, seed=seed)
         out[use_kernel] = gtr.fit(g0, source(1), resume=False)
         if use_kernel:
             assert_kernels("c GTC update", {"sparse_ce_tiles": 1,
                                             "gtc_compress_flat": n_leaves},
-                           gtr.updates["distill_topk"], g0, first.data, lr)
+                           gtr.updates["distill_topk"], g0,
+                           gtr.strategy.stack([first.data]), lr)
     pk, px = leaves(out[True].params), leaves(out[False].params)
     n_diff = sum(int(np.sum(a != b)) for a, b in zip(pk, px))
     n_all = sum(a.size for a in pk)
@@ -505,8 +508,8 @@ def four_chips(seed: int, n_dev: int = 4):
     from repro.distributed.bmuf import BMUFConfig
     from repro.launch.steps import make_loss_fn
     from repro.models import build_model
-    from repro.runtime.cluster import worker_mesh
-    from repro.train import (BMUFShardMap, BMUFVmap, TrainBatch, Trainer)
+    from repro.runtime.cluster import auto_mesh, worker_mesh
+    from repro.train import BMUFShardMap, TrainBatch, Trainer
 
     devs = jax.devices()
     check(len(devs) == n_dev and len({d.id for d in devs}) == n_dev,
@@ -523,15 +526,16 @@ def four_chips(seed: int, n_dev: int = 4):
     check(len({d.id for d in mesh.devices.flat}) == n_dev,
           f"worker mesh spans {mesh.devices.size} devices, want {n_dev}")
 
-    # BMUF: W=4 one worker per chip vs W=4 vmapped on one chip
+    # BMUF: W=4 one worker per chip vs W=4 on a one-chip mesh
     bcfg = BMUFConfig(n_workers=w, block_steps=2)
     n_up = 2
     data = _distill_batches(rng, n_up * bcfg.block_steps * w, pc)
     src = lambda: (TrainBatch(b, pc.lr, "distill_topk") for b in data)
     res = {}
     with jax.default_matmul_precision("highest"):
+        one_chip = auto_mesh((1,), ("data",), devices=devs[:1])
         for name, strat in (("shard_map", BMUFShardMap(bcfg, mesh)),
-                            ("vmap", BMUFVmap(bcfg))):
+                            ("one_chip", BMUFShardMap(bcfg, one_chip))):
             tr = Trainer(strat, {"distill_topk": loss})
             s0 = tr.init_state(params, seed=seed)
             st, t = timed(lambda: tr.fit(s0, src(), resume=False))
@@ -544,23 +548,23 @@ def four_chips(seed: int, n_dev: int = 4):
                                {"sparse_ce_tiles": 1},
                                tr.updates["distill_topk"], s0, stacked,
                                jnp.asarray(pc.lr, jnp.float32))
-    sm, vm = res["shard_map"], res["vmap"]
-    check(int(sm.step) == int(vm.step) == n_up, "4: BMUF update count")
+    sm, one = res["shard_map"], res["one_chip"]
+    check(int(sm.step) == int(one.step) == n_up, "4: BMUF update count")
     _spread_over("4 BMUF workers", sm.strategy_state["workers"], n_dev)
     _spread_over("4 BMUF optimizer state", sm.opt_state, n_dev)
     leaves = lambda t: [np.asarray(x, np.float64)
                         for x in jax.tree_util.tree_leaves(t)]
     moved = max(np.max(np.abs(a - b)) for a, b in
-                zip(leaves(vm.params), leaves(params)))
+                zip(leaves(one.params), leaves(params)))
     err = max(np.max(np.abs(a - b)) for a, b in
-              zip(leaves(sm.params), leaves(vm.params)))
+              zip(leaves(sm.params), leaves(one.params)))
     # at "highest" the two differ only in the order of f32 sums (the
     # cross-chip pmean, batched vs per-chip matmuls)
     check(moved > 0 and err <= HIGHEST_RTOL * 10 * moved,
           f"4: BMUF shard_map params off by {err:.3g} (update {moved:.3g})")
-    log("4", f"BMUF shard_map == vmap: max param diff {err:.3g} of update "
-             f"{moved:.3g}; worker replicas and optimizer state one per "
-             f"device")
+    log("4", f"BMUF four chips == one chip: max param diff {err:.3g} of "
+             f"update {moved:.3g}; worker replicas and optimizer state one "
+             f"per device")
 
     # GTC: the sharded W=4 step vs simulate_gtc_round, two rounds
     gcfg = gtc_lib.GTCConfig(tau=pc.gtc_tau, n_workers=w, use_kernel=True)

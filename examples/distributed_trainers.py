@@ -33,7 +33,7 @@ from repro.distributed.gtc import GTCConfig
 from repro.launch.steps import make_loss_fn
 from repro.models import build_model
 from repro.runtime.cluster import ClusterConfig, initialize, worker_mesh
-from repro.train import (GTC, BMUFVmap, GTCShardMap, ListSink, Trainer,
+from repro.train import (BMUFShardMap, GTCShardMap, ListSink, Trainer,
                          epoch_source)
 
 
@@ -65,15 +65,16 @@ def main():
     batches = pipe._batches(pipe.rng_labeled, chunked=True, seed=0)
     print(f"{len(batches)} chunked batches of {pc.batch}x{pc.chunk_len}")
 
-    print("\n== GTC (threshold compression, error feedback) ==")
-    _, sink = run(GTC(GTCConfig(tau=5e-4, n_workers=1)), "gtc",
+    print("\n== GTC (1 worker: threshold compression, error feedback) ==")
+    _, sink = run(GTCShardMap(GTCConfig(tau=5e-4, n_workers=1),
+                              worker_mesh(1)), "gtc",
                   model=model, cfg=cfg, batches=batches)
     dens = sink.last("gtc_density")
     print(f"  wire density {dens:.3f} "
           f"(bandwidth saving ~{1 / max(dens, 1e-3):.0f}x)")
 
     mesh = worker_mesh(2)     # widest device mesh 2 workers divide onto
-    print(f"\n== GTCShardMap (2 workers, int8 wire over a "
+    print(f"\n== GTC (2 workers, int8 wire over a "
           f"{mesh.devices.size}-device mesh) ==")
     run(GTCShardMap(GTCConfig(tau=5e-4, n_workers=2), mesh),
         "gtc_shardmap", model=model, cfg=cfg, batches=batches)
@@ -81,10 +82,11 @@ def main():
     bc = BMUFConfig(n_workers=4, block_steps=2)
     print(f"\n== BMUF ({bc.n_workers} workers, block sync every "
           f"{bc.block_steps} steps) ==")
-    run(BMUFVmap(bc), "bmuf", model=model, cfg=cfg, batches=batches)
+    run(BMUFShardMap(bc, worker_mesh(bc.n_workers)), "bmuf", model=model,
+        cfg=cfg, batches=batches)
 
-    print("\nGTC communicates every step (a compressed int8 psum — "
-          "GTCShardMap is the worker-axis-sharded form); BMUF every "
+    print("\nGTC communicates every step (a compressed int8 psum over "
+          "the worker axis); BMUF every "
           f"{bc.block_steps} steps (full model mean + block momentum).")
 
 

@@ -14,9 +14,9 @@ Stages (paper sections in brackets):
              threshold-compressed SGD
 
 Every training stage is one ``Trainer.fit()`` call (repro.train): the
-stage picks a DistributedStrategy (Local / BMUFVmap / GTC), a dict of
-loss fns, and a DataSource; the Trainer owns the jit (one executable
-per loss kind x batch shape, lr traced), periodic TrainState
+stage picks a DistributedStrategy (Local / BMUFShardMap / GTCShardMap),
+a dict of loss fns, and a DataSource; the Trainer owns the jit (one
+executable per loss kind x batch shape, lr traced), periodic TrainState
 checkpoints under <out>/ckpt_<stage>/state (killed stages resume
 mid-stream; completed stages retire their resume state), and the
 metrics sink.  Batches reach the jitted update through the async
@@ -56,7 +56,7 @@ from repro.models import build_model
 from repro.runtime.cluster import worker_mesh
 from repro.seqtrain import build_denominator_graph, make_smbr_loss_fn
 from repro.seqtrain.smbr import frame_error_rate
-from repro.train import (GTC, BMUFVmap, GTCShardMap, ListSink, Local,
+from repro.train import (BMUFShardMap, GTCShardMap, ListSink, Local,
                          TrainBatch, Trainer, chain, distill_shard_source,
                          epoch_source, scheduled_source)
 
@@ -171,10 +171,9 @@ class PipelineConfig:
     chunked_until: int = 3
     # trainers
     gtc_tau: float = 2e-4
-    gtc_workers: int = 2              # sMBR sequence-training workers:
-                                      # >1 runs GTCShardMap (int8 wire,
-                                      # worker axis on a mesh), 1 the
-                                      # single-process GTC strategy
+    gtc_workers: int = 2              # sMBR sequence-training workers
+                                      # of GTCShardMap (int8 wire, worker
+                                      # axis on a mesh)
     bmuf_workers: int = 4
     bmuf_block_steps: int = 2
     smbr_epochs: int = 2
@@ -438,9 +437,11 @@ class SSLPipeline:
     def _student_strategy(self):
         pc = self.pc
         if self.student_trainer == "bmuf":
-            return BMUFVmap(BMUFConfig(n_workers=pc.bmuf_workers,
-                                       block_steps=pc.bmuf_block_steps))
-        return GTC(GTCConfig(tau=pc.gtc_tau, n_workers=1))
+            return BMUFShardMap(BMUFConfig(n_workers=pc.bmuf_workers,
+                                           block_steps=pc.bmuf_block_steps),
+                                worker_mesh(pc.bmuf_workers))
+        return GTCShardMap(GTCConfig(tau=pc.gtc_tau, n_workers=1),
+                           worker_mesh(1))
 
     def stage_student(self, *, membership=None, init_params=None,
                       stage: str = None) -> Dict:
@@ -517,14 +518,11 @@ class SSLPipeline:
                     round(100 * (base_fer - fer) / max(base_fer, 1e-9), 2)}
 
     def _smbr_strategy(self):
-        """The paper's 16-GPU sMBR trainer: threshold-compressed SGD.
-        ``gtc_workers > 1`` runs the worker axis through GTCShardMap on
-        a mesh (the axis spans the devices when the worker count
-        divides them, else one device vmap-carries all workers — the
-        same math either way, pinned bitwise in tests)."""
+        """The paper's 16-GPU sMBR trainer: threshold-compressed SGD,
+        the worker axis on a mesh (the axis spans the devices when the
+        worker count divides them, else one device carries all workers
+        — the same math either way, pinned bitwise in tests)."""
         pc = self.pc
-        if pc.gtc_workers <= 1:
-            return GTC(GTCConfig(tau=pc.gtc_tau, n_workers=1), clip=0.0)
         # widest mesh the worker count divides onto: each device carries
         # workers/n_dev unrolled workers (all of them on 1 device at
         # laptop scale; one each on the paper's 16-GPU shape)

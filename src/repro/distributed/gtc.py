@@ -23,11 +23,12 @@ One code path owns the math.  ``compress_tree`` is the error-feedback
 selection (the fused Pallas kernel ``repro.kernels.gtc_compress`` on
 TPU, the bitwise-identical pure-jnp ref elsewhere;
 ``GTCConfig.use_kernel`` overrides that ``kernels._dispatch`` default);
-``pack_int8`` / ``unpack_int8`` are the only pack/unpack pair; ``wire_reduce`` is the wire itself — the same
-function serves the single-process ``train.GTC`` strategy (a degenerate
-pack/unpack round-trip), ``make_gtc_allreduce`` (inside an existing
-shard_map/pmap), and ``make_sharded_gtc_train_step`` (the
-worker-axis-sharded step that ``train.GTCShardMap`` wraps).
+``pack_int8`` / ``unpack_int8`` are the only pack/unpack pair;
+``wire_pack`` / ``wire_unpack`` are the wire itself.  Their one program
+caller is ``make_sharded_gtc_train_step``, the worker-axis-sharded step
+that ``train.GTCShardMap`` wraps (W=1 on a one-device mesh is the
+single-worker trainer).  ``simulate_gtc_round`` is the tests'
+independent reference of one exchange.
 
 Adaptive threshold: Strom fixes tau; we also provide the common variant
 that adapts tau per-tensor to hit a target sparsity, used when sweeping.
@@ -135,33 +136,16 @@ def wire_pack(send, cfg: GTCConfig):
     return p.astype(jnp.int32) if cfg.int32_accum else p
 
 def wire_unpack(acc, cfg: GTCConfig, *, axis_name: Optional[str] = None):
-    """Accumulated wire messages -> the averaged float update;
-    ``axis_name`` adds the cross-device psum (THE collective — at int8
-    width when quantized and not widened)."""
+    """Accumulated wire messages -> the update averaged over
+    ``cfg.n_workers`` (the paper applies the raw sum; the average keeps
+    the lr independent of the worker count); ``axis_name`` adds the
+    cross-device psum (THE collective — at int8 width when quantized and
+    not widened)."""
     if axis_name is not None:
         acc = jax.lax.psum(acc, axis_name)
     if cfg.quantize_int8:
         return unpack_int8(acc, cfg.tau, n_workers_summed=cfg.n_workers)
     return acc / cfg.n_workers if cfg.n_workers != 1 else acc
-
-
-def wire_reduce(sends, cfg: GTCConfig, *,
-                axis_name: Optional[str] = None):
-    """THE wire for one local worker: pack -> (psum) -> unpack-average,
-    one code path.  ``sends``: that worker's pytree of send tensors
-    (values in {-tau, 0, +tau}).  With no ``axis_name`` this is the
-    single-worker wire — for the int8 format a pack/unpack round-trip
-    that is bitwise-identity on ternary sends, so the single-process
-    strategy and the sharded step share the exact arithmetic.
-
-    Returns the update averaged over ``cfg.n_workers`` (the paper
-    applies the raw sum; we normalize so LR is worker-count
-    independent).  Multi-worker-per-device accumulation happens in
-    ``make_sharded_gtc_train_step`` via the same ``wire_pack`` /
-    ``wire_unpack`` pair.
-    """
-    return tmap(lambda s: wire_unpack(wire_pack(s, cfg), cfg,
-                                      axis_name=axis_name), sends)
 
 
 def wire_bytes_per_update(params, cfg: GTCConfig) -> int:
@@ -181,53 +165,13 @@ def wire_bytes_per_update(params, cfg: GTCConfig) -> int:
     return total
 
 
-def gtc_init(params, cfg: Optional[GTCConfig] = None):
-    """Error-feedback residuals.  With a ``cfg``, residuals are
-    per-worker: stacked on a leading W dim, even at W=1 (each worker
-    carries its own compression error — the state
-    ``make_sharded_gtc_train_step`` shards over the worker axis).
-    Without one, the single-process unstacked form."""
-    if cfg is not None:
-        return {"residual": tmap(
-            lambda p: jnp.zeros((cfg.n_workers,) + p.shape, jnp.float32),
-            params)}
-    return {"residual": tmap(lambda p: jnp.zeros(p.shape, jnp.float32),
-                             params)}
-
-
-def make_gtc_allreduce(cfg: GTCConfig, axis_name: str):
-    """Inside shard_map/pmap (one worker per shard): compress locally,
-    reduce the sparse message over ``axis_name`` via ``wire_reduce``."""
-    def allreduce(grads, gtc_state):
-        send, res = compress_tree(grads, gtc_state["residual"], cfg.tau,
-                                  use_kernel=cfg.use_kernel)
-        avg = wire_reduce(send, cfg, axis_name=axis_name)
-        return avg, {"residual": res}
-    return allreduce
-
-
-def make_gtc_train_step(loss_fn: Callable, optimizer_update: Callable,
-                        cfg: GTCConfig, axis_name: str):
-    """Data-parallel train step with GTC gradient exchange.
-
-    loss_fn(params, batch) -> (loss, metrics); runs inside shard_map with
-    `axis_name` = worker axis.  optimizer_update(params, grads, opt_state,
-    lr=) -> (params, opt_state).  lr is a traced argument of the returned
-    step — one compile serves every LR-schedule phase.
-    """
-    allreduce = make_gtc_allreduce(cfg, axis_name)
-
-    def step(params, opt_state, gtc_state, batch, lr):
-        (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, batch)
-        update, gtc_state = allreduce(grads, gtc_state)
-        params, opt_state = optimizer_update(params, update, opt_state,
-                                             lr=lr)
-        metrics = dict(metrics)
-        metrics["gtc_density"] = density(update, cfg.tau)
-        return params, opt_state, gtc_state, metrics
-
-    return step
+def gtc_init(params, cfg: GTCConfig):
+    """Error-feedback residuals, per worker: stacked on a leading W dim,
+    even at W=1 (each worker carries its own compression error — the
+    state ``make_sharded_gtc_train_step`` shards over the worker axis)."""
+    return {"residual": tmap(
+        lambda p: jnp.zeros((cfg.n_workers,) + p.shape, jnp.float32),
+        params)}
 
 
 # ------------------------------------------------------ shard_map wrapper
@@ -237,14 +181,15 @@ def make_sharded_gtc_train_step(loss_fn: Callable,
                                 cfg: GTCConfig, mesh,
                                 worker_axes=("data",),
                                 grad_transform: Optional[Callable] = None):
-    """Production GTC: the worker dim sharded over `worker_axes` of `mesh`.
+    """GTC with the worker dim sharded over `worker_axes` of `mesh`.
 
-    The multi-worker form of ``make_gtc_train_step`` with the worker
-    axis materialized: batches and error-feedback residuals carry a
-    leading W dim sharded over the mesh (each shard vmaps its local
-    worker slice), params/opt state are replicated (synchronous SGD:
-    every worker applies the same averaged update), and the exchange is
-    ``wire_reduce`` — local-W sum + one psum per leaf, int8-packed.
+    Batches and error-feedback residuals carry a leading W dim sharded
+    over the mesh (each shard unrolls its local worker slice),
+    params/opt state are replicated (synchronous SGD: every worker
+    applies the same averaged update), and the exchange is
+    ``wire_pack`` per worker, a local-W integer sum, then
+    ``wire_unpack`` — one psum per leaf, int8-packed.  At one worker the
+    wire is a pack/unpack round-trip, bitwise identity on ternary sends.
 
     loss_fn(params, batch[, rng]) -> (loss, metrics); a loss declaring
     ``rng`` receives a per-(update, worker) folded key — folded OUTSIDE
@@ -279,10 +224,10 @@ def make_sharded_gtc_train_step(loss_fn: Callable,
             return tmap(lambda s: wire_pack(s, cfg), send), new_res, m
 
         # the local worker slice is unrolled, not vmapped: each worker's
-        # compute lowers exactly as the single-worker path does (that is
-        # what makes the W=1 strategy-equivalence and the
-        # simulate_gtc_round comparisons *bitwise*, not approximate), and
-        # in production the slice is one worker per device anyway
+        # compute lowers exactly as a lone worker's does (that is what
+        # makes the simulate_gtc_round comparisons *bitwise*, not
+        # approximate), and in production the slice is one worker per
+        # device anyway
         local_w = jax.tree_util.tree_leaves(residuals)[0].shape[0]
         acc, res_i, ms_i = None, [], []
         for i in range(local_w):
@@ -358,7 +303,7 @@ def simulate_gtc_round(grads_per_worker, residuals_per_worker, tau: float,
     (applied_update, new_residuals).  grads/residuals: lists per worker.
 
     ``quantize_int8`` reproduces the packed wire exactly as
-    ``wire_reduce`` ships it: each worker's send packed to ternary int8,
+    ``make_sharded_gtc_train_step`` ships it: each worker's send packed to ternary int8,
     summed at integer width (int8 unless ``int32_accum``), unpacked and
     averaged — integer sums are exact, so the sharded trainer must match
     this bitwise.

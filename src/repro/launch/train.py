@@ -74,9 +74,9 @@ def main(argv=None):
                     help="target-generation workers (ledgered disjoint "
                          "shard ranges; default: PipelineConfig's 2)")
     ap.add_argument("--gtc-workers", type=int, default=None,
-                    help="sMBR sequence-training workers: >1 runs the "
-                         "stage through GTCShardMap (int8 wire over a "
-                         "mesh worker axis; default: PipelineConfig's 2)")
+                    help="sMBR sequence-training workers of "
+                         "GTCShardMap (int8 wire over a mesh worker "
+                         "axis; default: PipelineConfig's 2)")
     ap.add_argument("--prefetch", type=int, default=None,
                     help="async feed depth for Trainer.fit "
                          "(0 = synchronous; default: PipelineConfig's 2)")

@@ -1,8 +1,7 @@
 """Unified Trainer API (ISSUE 2) + the data-plane feed (ISSUE 3).
 
   TrainState            — params + opt + step + rng + strategy state
-  DistributedStrategy   — Local / BMUFVmap / BMUFShardMap / GTC /
-                          GTCShardMap
+  DistributedStrategy   — Local / BMUFShardMap / GTCShardMap
   DataSource            — iterables of TrainBatch (epoch_source,
                           distill_shard_source, scheduled_source, chain);
                           compose with repro.pipeline.PrefetchingSource
@@ -20,15 +19,14 @@ from repro.train.data import (DataSource, TrainBatch, chain,
                               scheduled_source)
 from repro.train.metrics import JsonlSink, ListSink, MetricsSink
 from repro.train.state import TrainState, restack_workers
-from repro.train.strategies import (GTC, BMUFShardMap, BMUFVmap,
-                                    DistributedStrategy, GTCShardMap,
-                                    Local, init_opt, make_sgd_step)
+from repro.train.strategies import (BMUFShardMap, DistributedStrategy,
+                                    GTCShardMap, Local, init_opt,
+                                    make_sgd_step)
 from repro.train.trainer import Trainer
 
 __all__ = [
     "TrainState", "Trainer", "TrainBatch", "DataSource",
-    "DistributedStrategy", "Local", "BMUFVmap", "BMUFShardMap", "GTC",
-    "GTCShardMap",
+    "DistributedStrategy", "Local", "BMUFShardMap", "GTCShardMap",
     "make_sgd_step", "init_opt", "restack_workers",
     "epoch_source", "distill_shard_source", "scheduled_source", "chain",
     "PrefetchingSource", "Schedule",

@@ -5,22 +5,21 @@ strategy is the only part that differs, and it is a constructor argument
 instead of a forked code path:
 
   Local          — single-worker SGD/Adam (baseline CE, teacher, smoke)
-  BMUFVmap       — blockwise model-update filtering, workers on a leading
-                   vmapped W dim (paper §3.5's 64-GPU trainer, CPU/test
-                   execution of the same math)
-  BMUFShardMap   — identical math with the W dim sharded over mesh axes
-                   (the production path in distributed/bmuf.py)
-  GTC            — Strom threshold-compressed SGD with error feedback
+  BMUFShardMap   — blockwise model-update filtering (paper §3.5's 64-GPU
+                   trainer): worker replicas on a leading W dim sharded
+                   over mesh axes, one device holding them all on a
+                   one-device mesh (distributed/bmuf.py)
+  GTCShardMap    — Strom threshold-compressed SGD with error feedback
                    (paper §2/§3.4's 16-GPU trainer; works with any loss,
-                   including sMBR), single-process form
-  GTCShardMap    — the same math with the worker axis sharded over mesh
-                   axes: per-worker residuals, int8-packed wire psum
-                   (the production path in distributed/gtc.py)
+                   including sMBR): per-worker residuals on the sharded
+                   worker axis, int8-packed wire psum; W=1 on a
+                   one-device mesh is the single-worker form
+                   (distributed/gtc.py)
 
 A strategy exposes:
 
   microbatches          how many source batches one update consumes
-                        (1 for Local/GTC; tau*W for BMUF)
+                        (1 for Local; W for GTC; tau*W for BMUF)
   n_workers             the *current* worker membership W — a runtime
                         value, not a construction-time constant
   stack(group)          fold that many batches into the update's input
@@ -42,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Protocol, runtime_checkable
+from typing import Any, Callable, List, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -115,29 +114,23 @@ class DistributedStrategy(Protocol):
     def resize(self, state: "TrainState", w_new: int) -> "TrainState": ...
 
 
-class _SingleWorker:
-    """resize() for the strategies with no worker-stacked state: the
-    only membership they can express is W=1, so any other target is a
-    caller error, not something to silently absorb."""
-
-    n_workers = 1
-
-    def resize(self, state: TrainState, w_new: int) -> TrainState:
-        if w_new != 1:
-            raise ValueError(
-                f"{type(self).__name__} is single-worker; cannot resize "
-                f"to W={w_new}")
-        return state
-
-
-class Local(_SingleWorker):
+class Local:
     """Plain single-worker training — the degenerate strategy."""
 
     microbatches = 1
+    n_workers = 1
 
     def __init__(self, *, optimizer: str = "momentum", clip: float = 1.0):
         self.optimizer = optimizer
         self.clip = clip
+
+    def resize(self, state: TrainState, w_new: int) -> TrainState:
+        """The only membership Local can express is W=1: any other
+        target is a caller error, not something to silently absorb."""
+        if w_new != 1:
+            raise ValueError(
+                f"Local is single-worker; cannot resize to W={w_new}")
+        return state
 
     def init_opt(self, params):
         return init_opt(params, self.optimizer)
@@ -160,69 +153,6 @@ class Local(_SingleWorker):
             params, opt, metrics = step(state.params, state.opt_state,
                                         batch, lr, rng)
             return state.replace(params=params, opt_state=opt,
-                                 step=state.step + 1), metrics
-
-        return update
-
-
-class GTC(_SingleWorker):
-    """Threshold-compressed SGD with error feedback (Strom 2015).
-
-    Single-process form: grads are compressed against the carried
-    residual by ``gtc_lib.compress_tree`` (the shared code path — the
-    Pallas kernel on TPU unless ``cfg.use_kernel`` says otherwise) and
-    the update ships
-    through ``gtc_lib.wire_reduce``, which at one worker is a
-    pack/unpack round-trip (bitwise identity on ternary sends) — so the
-    arithmetic is literally the multi-worker wire's.  The accuracy-
-    relevant math of the 16-GPU trainer, loss-agnostic (CE, distill,
-    sMBR).  The multi-worker exchange is ``GTCShardMap``.
-    """
-
-    microbatches = 1
-
-    def __init__(self, cfg: gtc_lib.GTCConfig = None, *,
-                 optimizer: str = "momentum", clip: float = 1.0):
-        self.cfg = cfg or gtc_lib.GTCConfig(n_workers=1)
-        if self.cfg.n_workers != 1:
-            raise ValueError(
-                f"GTC is the single-process strategy; cfg.n_workers="
-                f"{self.cfg.n_workers} needs GTCShardMap")
-        self.optimizer = optimizer
-        self.clip = clip
-
-    def init_opt(self, params):
-        return init_opt(params, self.optimizer)
-
-    def init_state(self, params):
-        return gtc_lib.gtc_init(params)
-
-    def stack(self, group):
-        return group[0]
-
-    def make_update(self, loss_fn):
-        upd = momentum_update if self.optimizer == "momentum" \
-            else adam_update
-        cfg = self.cfg
-        clip = self.clip
-
-        def update(state: TrainState, batch, lr):
-            rng = jax.random.fold_in(state.rng, state.step)
-            (_, metrics), grads = jax.value_and_grad(
-                lambda p, b: call_loss(loss_fn, p, b, rng),
-                has_aux=True)(state.params, batch)
-            if clip:
-                grads, gn = clip_by_global_norm(grads, clip)
-                metrics["grad_norm"] = gn
-            send, res = gtc_lib.compress_tree(
-                grads, state.strategy_state["residual"], cfg.tau,
-                use_kernel=cfg.use_kernel)
-            applied = gtc_lib.wire_reduce(send, cfg)
-            params, opt = upd(state.params, applied, state.opt_state,
-                              lr=lr)
-            metrics["gtc_density"] = gtc_lib.density(applied, cfg.tau)
-            return state.replace(params=params, opt_state=opt,
-                                 strategy_state={"residual": res},
                                  step=state.step + 1), metrics
 
         return update
@@ -253,18 +183,18 @@ class GTCShardMap:
     each update consumes ``n_workers`` microbatches (one per worker,
     stacked on a leading W dim and sharded over the mesh), every worker
     compresses its clipped grads against its own carried error-feedback
-    residual (``TrainState.strategy_state`` — per-worker, W-stacked),
-    and the wire is ``gtc_lib.wire_reduce``: int8-packed sends, integer
-    accumulation (int8-exact to 127 workers, int32 beyond), one psum
-    per leaf.  Params and optimizer state stay replicated — synchronous
-    SGD, every worker applies the same averaged update.
+    residual (``TrainState.strategy_state`` — per-worker, W-stacked,
+    even at W=1), and the wire is ``gtc_lib.wire_pack`` /
+    ``wire_unpack``: int8-packed sends, integer accumulation (int8-exact
+    to 127 workers, int32 beyond), one psum per leaf.  Params and
+    optimizer state stay replicated — synchronous SGD, every worker
+    applies the same averaged update.
 
-    On a 1-device mesh with n_workers=1 and a deterministic loss this
-    is bitwise-equal to the single-process ``GTC`` strategy (pinned in
-    tests) — the BMUFVmap/BMUFShardMap validation story, repeated for
-    the second of the paper's two distributed trainers.  Stochastic
-    losses get per-(update, worker) folded keys (global worker index,
-    folded outside the shard_map), matching the BMUF folding scheme.
+    W=1 on a one-device mesh is the single-worker trainer: its wire is a
+    pack/unpack round-trip, bitwise identity on the ternary sends.
+    Stochastic losses get per-(update, worker) folded keys (global
+    worker index, folded outside the shard_map), matching the BMUF
+    folding scheme.
     """
 
     def __init__(self, cfg: gtc_lib.GTCConfig, mesh, *,
@@ -367,12 +297,26 @@ class GTCShardMap:
         return update
 
 
-class _BMUFBase:
-    """Shared plumbing of the two BMUF execution paths."""
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_local(parts, lead):
+    """One device's share of a block: its batches stacked into
+    ``lead`` = (tau, workers on the device) leading dims, on the device
+    that holds them."""
+    return jnp.stack(parts).reshape(lead + parts[0].shape)
 
-    def __init__(self, cfg: bmuf_lib.BMUFConfig, *,
-                 optimizer: str = "momentum", clip: float = 1.0):
+
+class BMUFShardMap:
+    """BMUF with the worker dim sharded over mesh axes; a one-device mesh
+    holds every worker."""
+
+    places_block = True
+
+    def __init__(self, cfg: bmuf_lib.BMUFConfig, mesh, *,
+                 worker_axes=("data",), optimizer: str = "momentum",
+                 clip: float = 1.0):
         self.cfg = cfg
+        self.mesh = mesh
+        self.worker_axes = worker_axes
         self.optimizer = optimizer
         self.clip = clip
 
@@ -393,15 +337,19 @@ class _BMUFBase:
         The block-momentum ``delta`` is global and carries unchanged,
         which is why a shrink-mid-run matches a fresh smaller-W run
         only to float32-ULP (the momentum history differs from a
-        cold start) — pinned in tests."""
+        cold start) — pinned in tests.  The mesh is rebuilt for the new
+        W when this strategy owns a plain 1-axis worker mesh."""
         if w_new == self.cfg.n_workers:
             return state
         self.cfg = dataclasses.replace(self.cfg, n_workers=w_new)
         ss = dict(state.strategy_state)
         ss["workers"] = restack_workers(ss["workers"], w_new)
-        return state.replace(
+        if len(self.worker_axes) == 1:
+            from repro.runtime.cluster import worker_mesh
+            self.mesh = worker_mesh(w_new, axis=self.worker_axes[0])
+        return self.place(state.replace(
             opt_state=restack_workers(state.opt_state, w_new),
-            strategy_state=ss)
+            strategy_state=ss))
 
     def init_opt(self, params):
         one = init_opt(params, self.optimizer)
@@ -411,72 +359,6 @@ class _BMUFBase:
     def init_state(self, params):
         st = bmuf_lib.bmuf_init(params, self.cfg)
         return {"delta": st["delta"], "workers": st["workers"]}
-
-    def stack(self, group):
-        tau, w = self.cfg.block_steps, self.cfg.n_workers
-        return tmap(lambda *xs: jnp.stack(
-            [jnp.asarray(x) for x in xs]).reshape(tau, w, *xs[0].shape),
-            *group)
-
-    def _block(self, loss_fn):
-        raise NotImplementedError
-
-    def make_update(self, loss_fn):
-        block = self._block(loss_fn)
-
-        def update(state: TrainState, batches, lr):
-            rng = jax.random.fold_in(state.rng, state.step)
-            bstate = {"theta_g": state.params, **state.strategy_state}
-            bstate, opts, ms = block(bstate, state.opt_state, batches, lr,
-                                     rng)
-            # metrics arrive (W, tau)-shaped from the vmapped scan
-            metrics = tmap(jnp.mean, ms)
-            return state.replace(
-                params=bstate["theta_g"], opt_state=opts,
-                strategy_state={"delta": bstate["delta"],
-                                "workers": bstate["workers"]},
-                step=state.step + 1), metrics
-
-        return update
-
-
-class BMUFVmap(_BMUFBase):
-    """BMUF with the worker dim vmapped on one device (tests / laptop)."""
-
-    def _block(self, loss_fn):
-        step = make_sgd_step(loss_fn, optimizer=self.optimizer,
-                             clip=self.clip)
-        return bmuf_lib.make_bmuf_block_step(step, self.cfg)
-
-
-@functools.partial(jax.jit, static_argnums=1)
-def _stack_local(parts, lead):
-    """One device's share of a block: its batches stacked into
-    ``lead`` = (tau, workers on the device) leading dims, on the device
-    that holds them."""
-    return jnp.stack(parts).reshape(lead + parts[0].shape)
-
-
-class BMUFShardMap(_BMUFBase):
-    """BMUF with the worker dim sharded over mesh axes (production)."""
-
-    places_block = True
-
-    def __init__(self, cfg: bmuf_lib.BMUFConfig, mesh, *,
-                 worker_axes=("data",), optimizer: str = "momentum",
-                 clip: float = 1.0):
-        super().__init__(cfg, optimizer=optimizer, clip=clip)
-        self.mesh = mesh
-        self.worker_axes = worker_axes
-
-    def resize(self, state: TrainState, w_new: int) -> TrainState:
-        if w_new == self.cfg.n_workers:
-            return state
-        state = super().resize(state, w_new)
-        if len(self.worker_axes) == 1:
-            from repro.runtime.cluster import worker_mesh
-            self.mesh = worker_mesh(w_new, axis=self.worker_axes[0])
-        return self.place(state)
 
     def place(self, state: TrainState) -> TrainState:
         """Worker replicas and per-worker optimizer state sharded over
@@ -497,10 +379,9 @@ class BMUFShardMap(_BMUFBase):
         """The block in the update's own input sharding, (tau, W, ...)
         with W over the worker axes of the mesh held now (after a
         ``resize``, the new W's): batch j is local step j // W of worker
-        j % W, as ``_BMUFBase.stack`` orders it.  Each device is sent
-        only its own workers' batches, straight from where they are
-        (host memory, or another device for a ``jax.Array``), and stacks
-        them itself; nothing goes through the default device, so the
+        j % W.  Each device is sent only its own workers' batches,
+        straight from where they are (host memory, or another device for
+        a ``jax.Array``), and stacks them itself; nothing goes through the default device, so the
         update's dispatch has nothing to reshard, and nothing waits on
         the device."""
         from jax.sharding import NamedSharding
@@ -523,8 +404,23 @@ class BMUFShardMap(_BMUFBase):
 
         return tmap(lay_out, *group)
 
-    def _block(self, loss_fn):
+    def make_update(self, loss_fn):
         step = make_sgd_step(loss_fn, optimizer=self.optimizer,
                              clip=self.clip)
-        return bmuf_lib.make_sharded_bmuf_block_step(
+        block = bmuf_lib.make_sharded_bmuf_block_step(
             step, self.cfg, self.mesh, worker_axes=self.worker_axes)
+
+        def update(state: TrainState, batches, lr):
+            rng = jax.random.fold_in(state.rng, state.step)
+            bstate = {"theta_g": state.params, **state.strategy_state}
+            bstate, opts, ms = block(bstate, state.opt_state, batches, lr,
+                                     rng)
+            # metrics arrive (W, tau)-shaped from the vmapped scan
+            metrics = tmap(jnp.mean, ms)
+            return state.replace(
+                params=bstate["theta_g"], opt_state=opts,
+                strategy_state={"delta": bstate["delta"],
+                                "workers": bstate["workers"]},
+                step=state.step + 1), metrics
+
+        return update

@@ -227,8 +227,8 @@ class Trainer:
             # or lr boundary; drop it (BMUF block semantics — blocks
             # stack their microbatches, so ragged full-sequence batches
             # only fill blocks with exact shape-mates, and a block never
-            # blurs two schedule phases' lrs.  Local/GTC never hit this:
-            # need == 1 means no block is ever partial).  Schedule
+            # blurs two schedule phases' lrs.  Local and GTC at W=1 never
+            # hit this: need == 1 means no block is ever partial).  Schedule
             # objects compare by identity, so one schedule spanning many
             # updates never splits a block.
             sig = _shape_sig(tb.data) if need > 1 else None

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.bmuf import BMUFConfig
-from repro.train import BMUFShardMap, BMUFVmap, TrainBatch, Trainer
+from repro.train import BMUFShardMap, TrainBatch, Trainer
 
 W, NDEV, TAU, D = int(sys.argv[1]), int(sys.argv[2]), 2, 8
 
@@ -69,29 +69,35 @@ for shard in block["j"].addressable_shards:
 assert got == {(s, w): s * W + w for s in range(TAU) for w in range(W)}, got
 print("LAYOUT OK")
 
+def plain_stack(group):
+    """The block stacked on the default device, (tau, W, ...)."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(
+        [jnp.asarray(x) for x in xs]).reshape(TAU, W, *xs[0].shape), *group)
+
 # two blocks through Trainer.fit: equal to the same update fed by the
-# plain stack, and to BMUFVmap on the same batches
+# plain stack, and to W workers on a one-device mesh on the same batches
 def leaves(state):
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(
         (state.params, state.opt_state, state.strategy_state, state.step))]
 
 _, _, new = fit(BMUFShardMap(cfg, mesh, clip=1.0))
 plain = BMUFShardMap(cfg, mesh, clip=1.0)
-plain.stack = lambda group: BMUFVmap.stack(plain, group)
+plain.stack = plain_stack
 _, _, old = fit(plain)
-_, _, vmap = fit(BMUFVmap(cfg, clip=1.0))
+_, _, one = fit(BMUFShardMap(cfg, Mesh(np.array(jax.devices()[:1]),
+                                       ("data",)), clip=1.0))
 assert int(new.step) == 2
 for a, b in zip(leaves(new), leaves(old)):
     np.testing.assert_array_equal(a, b)
 print("EQUAL PLAIN STACK")
-for a, b in zip(leaves(new), leaves(vmap)):
+for a, b in zip(leaves(new), leaves(one)):
     if NDEV == 1:
         np.testing.assert_array_equal(a, b)
     else:
         # the cross-device pmean sums the workers in another order than
-        # the vmapped mean: float32 rounding, never more
+        # the one device's mean over all W: float32 rounding, never more
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-print("EQUAL VMAP")
+print("EQUAL ONE DEVICE")
 
 # no data moves at the update's dispatch: one block of Trainer.fit under
 # guards that refuse implicit device-to-device and device-to-host copies
@@ -103,7 +109,7 @@ assert int(out.step) == 1
 print("GUARDED BLOCK OK")
 # the control: the plain stack on the default device, fed to the same
 # sharded update, has to be resharded at dispatch
-plain_block = BMUFVmap.stack(tr.strategy, batches[:TAU * W])
+plain_block = plain_stack(batches[:TAU * W])
 try:
     with jax.transfer_guard_device_to_device("disallow"), \
             jax.transfer_guard_device_to_host("disallow"):
@@ -136,11 +142,11 @@ def test_block_layout_and_equality(w, ndev):
     step j // W, in the update's ``P(None, worker axes)`` sharding; two
     blocks of ``Trainer.fit`` then give params, optimizer state and block
     momentum bitwise equal to the same update fed by the plain stack, and
-    to ``BMUFVmap`` (bitwise on one device, to float32 rounding of the
-    cross-device mean on four)."""
+    to ``BMUFShardMap`` on a one-device mesh (bitwise on one device, to
+    float32 rounding of the cross-device mean on four)."""
     rc, out, err = _feed(w, ndev)
     assert rc == 0, err
-    for mark in ("LAYOUT OK", "EQUAL PLAIN STACK", "EQUAL VMAP"):
+    for mark in ("LAYOUT OK", "EQUAL PLAIN STACK", "EQUAL ONE DEVICE"):
         assert mark in out, (mark, out, err)
 
 

@@ -198,9 +198,8 @@ def test_pack_int8_overflow_guard():
         G.pack_int8(s, 1e-3, n_workers=128)
     G.pack_int8(s, 1e-3, n_workers=128, int32_accum=True)  # widened: fine
     with pytest.raises(ValueError):
-        G.wire_reduce({"w": s}, G.GTCConfig(tau=1e-3, n_workers=200))
-    G.wire_reduce({"w": s}, G.GTCConfig(tau=1e-3, n_workers=200,
-                                        int32_accum=True))
+        G.wire_pack(s, G.GTCConfig(tau=1e-3, n_workers=200))
+    G.wire_pack(s, G.GTCConfig(tau=1e-3, n_workers=200, int32_accum=True))
 
 
 def test_unpack_int8_averages_summed_workers():
@@ -215,14 +214,15 @@ def test_unpack_int8_averages_summed_workers():
 
 def test_wire_reduce_single_worker_is_identity_on_sends():
     """The degenerate wire (pack -> unpack, no axes) is bitwise-identity
-    on ternary sends — what lets the single-process GTC strategy share
-    the multi-worker arithmetic."""
+    on ternary sends — what lets GTCShardMap at W=1 be the single-worker
+    trainer with the multi-worker arithmetic."""
     tau = 1e-3
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.normal(size=(33,)) * tau, jnp.float32)
     s, _ = G.compress_leaf(g, jnp.zeros((33,)), tau)
-    out = G.wire_reduce({"w": s}, G.GTCConfig(tau=tau, n_workers=1))
-    np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(s))
+    cfg = G.GTCConfig(tau=tau, n_workers=1)
+    out = G.wire_unpack(G.wire_pack(s, cfg), cfg)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(s))
 
 
 def test_gtc_ring_converges_to_mean():
@@ -247,14 +247,15 @@ def test_gtc_ring_converges_to_mean():
 
 
 def test_gtc_strategy_matches_compress_tree():
-    """The train.GTC strategy's update == the manual reference: grads
+    """GTCShardMap's update at W=1 == the manual reference: grads
     compressed by gtc_lib.compress_tree against the carried residual,
     with the *sent* sparse tensor driving the optimizer."""
-    from repro.train import GTC as GTCStrategy, Trainer
+    from repro.train import GTCShardMap, Trainer
     x, y = _problem(n=32)
     params = {"w": jnp.zeros((8,))}
     tau = 1e-3
-    strat = GTCStrategy(G.GTCConfig(tau=tau, n_workers=1), clip=0.0)
+    strat = GTCShardMap(G.GTCConfig(tau=tau, n_workers=1), worker_mesh(1),
+                        clip=0.0)
     tr = Trainer(strat, {"quad": quad_loss})
     state = tr.init_state(params)
     lr = 0.05
@@ -264,7 +265,7 @@ def test_gtc_strategy_matches_compress_tree():
     ref_res = {"w": jnp.zeros((8,))}
     batch = {"x": x, "y": y}
     for _ in range(3):
-        state, _ = tr.updates["quad"](state, batch,
+        state, _ = tr.updates["quad"](state, strat.stack([batch]),
                                       jnp.asarray(lr, jnp.float32))
         (_, _), g = jax.value_and_grad(quad_loss, has_aux=True)(
             ref_params, batch)
@@ -274,7 +275,7 @@ def test_gtc_strategy_matches_compress_tree():
     np.testing.assert_allclose(np.asarray(state.params["w"]),
                                np.asarray(ref_params["w"]), rtol=1e-6)
     np.testing.assert_allclose(
-        np.asarray(state.strategy_state["residual"]["w"]),
+        np.asarray(state.strategy_state["residual"]["w"][0]),
         np.asarray(ref_res["w"]), rtol=1e-6)
 
 
@@ -293,12 +294,13 @@ def _stack(dicts):
 
 
 @pytest.mark.parametrize("n_workers,quantize",
-                         [(2, True), (4, True), (2, False)])
+                         [(1, True), (2, True), (4, True), (2, False)])
 def test_sharded_gtc_wire_matches_simulate_bitwise(n_workers, quantize):
     """The tentpole pin: make_sharded_gtc_train_step's applied update
     and per-worker residuals == simulate_gtc_round, BITWISE, for both
     the float and the packed-int8 wire (integer accumulation is exact,
-    so the shard_map plumbing must add nothing)."""
+    so the shard_map plumbing must add nothing).  W=1 is the
+    single-worker trainer: its wire is a pack/unpack round-trip."""
     tau = 1e-3
     cfg = G.GTCConfig(tau=tau, n_workers=n_workers, quantize_int8=quantize)
     mesh = worker_mesh(n_workers)
@@ -324,34 +326,6 @@ def test_sharded_gtc_wire_matches_simulate_bitwise(n_workers, quantize):
                 np.asarray(state["residual"]["w"][w]),
                 np.asarray(ref_res[w]["w"]),
                 err_msg=f"residual, worker {w}, round {it}")
-
-
-def test_gtc_shardmap_w1_bitwise_equals_gtc_strategy():
-    """GTCShardMap at n_workers=1 on a 1-device mesh == the
-    single-process GTC strategy, bitwise on params AND residual — the
-    BMUFVmap/BMUFShardMap validation story for the second trainer."""
-    from repro.train import GTC as GTCStrategy, GTCShardMap, Trainer, \
-        TrainBatch
-    x, y = _problem(n=32)
-    batch = {"x": x, "y": y}
-    params = {"w": jnp.zeros((8,))}
-    tau = 1e-3
-    src = lambda: [TrainBatch(batch, 0.05, "quad") for _ in range(5)]
-
-    tr1 = Trainer(GTCStrategy(G.GTCConfig(tau=tau, n_workers=1), clip=0.0),
-                  {"quad": quad_loss})
-    s1 = tr1.fit(tr1.init_state(params), src(), resume=False)
-
-    mesh = worker_mesh(1)
-    trs = Trainer(GTCShardMap(G.GTCConfig(tau=tau, n_workers=1), mesh,
-                              clip=0.0), {"quad": quad_loss})
-    ss = trs.fit(trs.init_state(params), src(), resume=False)
-    assert int(s1.step) == int(ss.step) == 5
-    np.testing.assert_array_equal(np.asarray(s1.params["w"]),
-                                  np.asarray(ss.params["w"]))
-    np.testing.assert_array_equal(
-        np.asarray(s1.strategy_state["residual"]["w"]),
-        np.asarray(ss.strategy_state["residual"]["w"][0]))
 
 
 def test_sharded_gtc_residual_conservation():
@@ -388,24 +362,25 @@ def test_gtc_strategy_kernel_path_matches_ref():
     test_kernels pins that; across a *full jitted update* the pallas_call
     boundary blocks the elementwise fusion XLA applies to the inline
     ref, so the carried residual can drift by ~1 ulp.)"""
-    from repro.train import GTC as GTCStrategy, Trainer, TrainBatch
+    from repro.train import GTCShardMap, Trainer, TrainBatch
     x, y = _problem(n=32)
     batch = {"x": x, "y": y}
     params = {"w": jnp.zeros((8,))}
     src = lambda: [TrainBatch(batch, 0.05, "quad") for _ in range(3)]
     outs = []
     for use_kernel in (False, True):
-        tr = Trainer(GTCStrategy(G.GTCConfig(tau=1e-3, n_workers=1,
+        tr = Trainer(GTCShardMap(G.GTCConfig(tau=1e-3, n_workers=1,
                                              use_kernel=use_kernel),
-                                 clip=0.0), {"quad": quad_loss})
+                                 worker_mesh(1), clip=0.0),
+                     {"quad": quad_loss})
         st = tr.fit(tr.init_state(params), src(), resume=False)
         outs.append(st)
     np.testing.assert_allclose(np.asarray(outs[0].params["w"]),
                                np.asarray(outs[1].params["w"]),
                                rtol=1e-6)
     np.testing.assert_allclose(
-        np.asarray(outs[0].strategy_state["residual"]["w"]),
-        np.asarray(outs[1].strategy_state["residual"]["w"]),
+        np.asarray(outs[0].strategy_state["residual"]["w"][0]),
+        np.asarray(outs[1].strategy_state["residual"]["w"][0]),
         rtol=1e-5, atol=1e-6)
 
 

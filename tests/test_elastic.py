@@ -33,8 +33,8 @@ from repro.pipeline.generate import WorkLedger, shard_ranges
 from repro.runtime import procs
 from repro.runtime.cluster import worker_mesh
 from repro.runtime.workers import LaneCrashPlan, TrainerMembership
-from repro.train import (BMUFVmap, GTCShardMap, Local, TrainBatch, Trainer,
-                         TrainState, restack_workers)
+from repro.train import (BMUFShardMap, GTCShardMap, Local, TrainBatch,
+                         Trainer, TrainState, restack_workers)
 from repro.train.state import worker_dim
 
 tmap = jax.tree_util.tree_map
@@ -226,8 +226,9 @@ def test_trainer_elastic_kill_matches_cross_w_resume(tmp_path):
     params = {"w": jnp.zeros((D,))}
 
     # --- run A: elastic shrink mid-run
-    trA = Trainer(BMUFVmap(B.BMUFConfig(n_workers=4, block_steps=1),
-                           clip=0.0), {"quad": quad_loss},
+    trA = Trainer(BMUFShardMap(B.BMUFConfig(n_workers=4, block_steps=1),
+                               worker_mesh(4), clip=0.0),
+                  {"quad": quad_loss},
                   checkpoint=CheckpointStore(ck), ckpt_every=2)
     sA = trA.fit(trA.init_state(params), _src(batches[:8]), resume=False)
     assert int(sA.step) == 2                   # W=4 checkpoint on disk
@@ -243,8 +244,9 @@ def test_trainer_elastic_kill_matches_cross_w_resume(tmp_path):
     assert trA.resize_stats["count"] == 1
 
     # --- run B: fresh W=3 trainer, cross-W resume of the W=4 save
-    trB = Trainer(BMUFVmap(B.BMUFConfig(n_workers=3, block_steps=1),
-                           clip=0.0), {"quad": quad_loss},
+    trB = Trainer(BMUFShardMap(B.BMUFConfig(n_workers=3, block_steps=1),
+                               worker_mesh(3), clip=0.0),
+                  {"quad": quad_loss},
                   checkpoint=CheckpointStore(ck))
     sB = trB.fit(trB.init_state(params), _src(batches), resume=True)
     assert int(sB.step) == 4
@@ -261,8 +263,9 @@ def test_trainer_revive_grows_back(tmp_path):
     for i in range(4):
         m.join(f"lane{i}")
     plan = LaneCrashPlan(m, kills={1: "lane2"}, revives={3: "lane2"})
-    tr = Trainer(BMUFVmap(B.BMUFConfig(n_workers=4, block_steps=1),
-                          clip=0.0), {"quad": quad_loss})
+    tr = Trainer(BMUFShardMap(B.BMUFConfig(n_workers=4, block_steps=1),
+                              worker_mesh(4), clip=0.0),
+                 {"quad": quad_loss})
     # enough batches for 5 updates at worst-case W (partial tail dropped)
     state = tr.fit(tr.init_state({"w": jnp.zeros((D,))}),
                    _src(_batches(24)), resume=False, membership=plan)

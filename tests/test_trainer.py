@@ -11,10 +11,10 @@ import pytest
 from repro.checkpoint import CheckpointStore
 from repro.distributed.bmuf import BMUFConfig
 from repro.distributed.gtc import GTCConfig
-from repro.optim import momentum_init, momentum_update
+from repro.optim import momentum_init
 from repro.runtime.cluster import worker_mesh
-from repro.train import (GTC, BMUFVmap, JsonlSink, ListSink, Local,
-                         TrainBatch, Trainer, TrainState, chain,
+from repro.train import (BMUFShardMap, GTCShardMap, JsonlSink, ListSink,
+                         Local, TrainBatch, Trainer, TrainState, chain,
                          epoch_source, make_sgd_step)
 
 D = 8
@@ -96,8 +96,9 @@ def test_local_fit_converges():
 
 
 def test_bmuf_fit_matches_manual_block_step():
-    """BMUFVmap through Trainer.fit == driving bmuf_lib's block step by
-    hand: same theta_g after the same microbatch stream."""
+    """BMUFShardMap through Trainer.fit == driving bmuf_lib's vmap
+    reference block step by hand: same theta_g after the same
+    microbatch stream."""
     from repro.distributed import bmuf as bmuf_lib
     cfg = BMUFConfig(n_workers=2, block_steps=2, block_momentum=0.5)
     rng = np.random.default_rng(3)
@@ -107,7 +108,7 @@ def test_bmuf_fit_matches_manual_block_step():
         sel = rng.integers(0, 64, (16,))
         micro.append({"x": full["x"][sel], "y": full["y"][sel]})
 
-    strat = BMUFVmap(cfg, clip=0.0)
+    strat = BMUFShardMap(cfg, worker_mesh(2), clip=0.0)
     tr = Trainer(strat, {"quad": quad_loss})
     state = tr.fit(tr.init_state(_params()),
                    [TrainBatch(m, 0.05, "quad") for m in micro],
@@ -136,7 +137,7 @@ def test_bmuf_partial_block_dropped_at_loss_boundary():
     src = ([TrainBatch(batch, 0.05, "quad")] * 2      # 1 full block
            + [TrainBatch(batch, 0.05, "quad")]        # partial -> dropped
            + [TrainBatch(batch, 0.05, "other")] * 2)  # 1 full block
-    tr = Trainer(BMUFVmap(cfg, clip=0.0),
+    tr = Trainer(BMUFShardMap(cfg, worker_mesh(2), clip=0.0),
                  {"quad": quad_loss, "other": quad_loss})
     state = tr.fit(tr.init_state(_params()), src, resume=False)
     assert int(state.step) == 2
@@ -154,8 +155,6 @@ def test_gtc_shardmap_single_compile_across_lr_phases():
     ``_cache_size()``: on a >1-device mesh the C++ fastpath can hold a
     second cache entry for the same single executable."""
     import logging
-
-    from repro.train import GTCShardMap
 
     class _CompileCounter(logging.Handler):
         def __init__(self):
@@ -194,7 +193,6 @@ def test_gtc_shardmap_single_compile_across_lr_phases():
 def test_gtc_shardmap_groups_microbatches_per_worker():
     """Each update consumes n_workers microbatches; a trailing partial
     group is dropped (same block semantics as BMUF)."""
-    from repro.train import GTCShardMap
     batch = _problem(n=16)
     mesh = worker_mesh(2)
     tr = Trainer(GTCShardMap(GTCConfig(tau=1e-3, n_workers=2), mesh,
@@ -208,7 +206,6 @@ def test_gtc_shardmap_resume_preserves_worker_residuals(tmp_path):
     """The per-worker (W-stacked) error-feedback residuals round-trip
     through the checkpoint and the resumed run lands bitwise on the
     uninterrupted result."""
-    from repro.train import GTCShardMap
     batch = _problem(n=32)
     mesh = worker_mesh(2)
     lrs = [0.05] * 12                        # 6 updates at W=2
@@ -240,7 +237,6 @@ def test_gtc_shardmap_rng_distinct_per_worker():
     worker sees a distinct stream with the same folding scheme as the
     BMUF paths (global worker index, folded outside the shard_map)."""
     from repro.distributed import gtc as gtc_lib
-    from repro.train import GTCShardMap
 
     def spy_loss(params, batch, rng):
         noise = jax.random.normal(rng, ())
@@ -316,13 +312,13 @@ def test_resume_preserves_strategy_state(tmp_path):
     resume must not silently zero strategy state."""
     batch = _problem(n=32)
     lrs = [0.05] * 6
-    mk = lambda: Trainer(GTC(GTCConfig(tau=1e-3, n_workers=1), clip=0.0),
-                         {"quad": quad_loss},
+    gtc = lambda: GTCShardMap(GTCConfig(tau=1e-3, n_workers=1),
+                              worker_mesh(1), clip=0.0)
+    mk = lambda: Trainer(gtc(), {"quad": quad_loss},
                          checkpoint=CheckpointStore(
                              os.path.join(tmp_path, "state")),
                          ckpt_every=2)
-    ref = Trainer(GTC(GTCConfig(tau=1e-3, n_workers=1), clip=0.0),
-                  {"quad": quad_loss})
+    ref = Trainer(gtc(), {"quad": quad_loss})
     ref_state = ref.fit(ref.init_state(_params()), _source(batch, lrs),
                         resume=False)
     t1 = mk()
@@ -330,8 +326,8 @@ def test_resume_preserves_strategy_state(tmp_path):
     t2 = mk()
     state = t2.fit(t2.init_state(_params()), _source(batch, lrs))
     np.testing.assert_array_equal(
-        np.asarray(state.strategy_state["residual"]["w"]),
-        np.asarray(ref_state.strategy_state["residual"]["w"]))
+        np.asarray(state.strategy_state["residual"]["w"][0]),
+        np.asarray(ref_state.strategy_state["residual"]["w"][0]))
     np.testing.assert_array_equal(np.asarray(state.params["w"]),
                                   np.asarray(ref_state.params["w"]))
 
@@ -413,7 +409,8 @@ def test_bmuf_rng_distinct_per_worker_and_step():
         return jnp.mean(e ** 2), {"loss": jnp.mean(e ** 2)}
 
     batch = _problem(n=16)
-    strat = BMUFVmap(BMUFConfig(n_workers=2, block_steps=2), clip=0.0)
+    strat = BMUFShardMap(BMUFConfig(n_workers=2, block_steps=2),
+                         worker_mesh(2), clip=0.0)
     update = jax.jit(strat.make_update(spy_loss))
     state = Trainer(strat, {"noisy": spy_loss}).init_state(_params(),
                                                            seed=0)
@@ -430,34 +427,38 @@ def test_bmuf_rng_distinct_per_worker_and_step():
 
 
 def test_bmuf_sharded_rng_matches_vmap_path():
-    """Stochastic losses through BMUFShardMap == BMUFVmap bitwise on a
-    1-device mesh: the per-worker keys are folded with *global* worker
-    indices outside the shard_map (crossing as raw key data), so the
-    two execution paths of the same math stay interchangeable.  On a
-    >1-device mesh the cross-device psum reduction order shifts the
-    block mean by float32 ULPs, so equality relaxes to that tolerance."""
-    from repro.distributed.bmuf import BMUFConfig
-    from repro.train import BMUFShardMap
+    """Stochastic losses through BMUFShardMap == the vmap reference block
+    step driven by hand with the same folded keys, bitwise on a 1-device
+    mesh: the per-worker keys are folded with *global* worker indices
+    outside the shard_map (crossing as raw key data), so both forms of
+    the same math see the same streams.  On a >1-device mesh the
+    cross-device psum reduction order shifts the block mean by float32
+    ULPs, so equality relaxes to that tolerance."""
+    from repro.distributed import bmuf as bmuf_lib
 
     batch = _problem(n=32)
-    src = lambda: _source(batch, [0.05] * 8, "noisy")
     cfg = BMUFConfig(n_workers=2, block_steps=2, block_momentum=0.5)
 
-    tr_v = Trainer(BMUFVmap(cfg, clip=0.0), {"noisy": noisy_loss})
-    st_v = tr_v.fit(tr_v.init_state(_params(), seed=5), src(),
-                    resume=False)
-
     mesh = worker_mesh(2)
-    tr_s = Trainer(BMUFShardMap(cfg, mesh, clip=0.0),
-                   {"noisy": noisy_loss})
-    st_s = tr_s.fit(tr_s.init_state(_params(), seed=5), src(),
-                    resume=False)
-    assert int(st_v.step) == int(st_s.step) == 2
+    tr = Trainer(BMUFShardMap(cfg, mesh, clip=0.0), {"noisy": noisy_loss})
+    st0 = tr.init_state(_params(), seed=5)
+    st_s = tr.fit(st0, _source(batch, [0.05] * 8, "noisy"), resume=False)
+
+    block = jax.jit(bmuf_lib.make_bmuf_block_step(
+        make_sgd_step(noisy_loss, clip=0.0), cfg))
+    bstate = bmuf_lib.bmuf_init(_params(), cfg)
+    opt = jax.vmap(lambda _: momentum_init(_params()))(jnp.arange(2))
+    batches = jax.tree_util.tree_map(
+        lambda x: jnp.stack([x] * 4).reshape(2, 2, *x.shape), batch)
+    for step in range(2):
+        bstate, opt, _ = block(bstate, opt, batches, 0.05,
+                               jax.random.fold_in(st0.rng, step))
+    assert int(st_s.step) == 2
     if mesh.devices.size == 1:
-        np.testing.assert_array_equal(np.asarray(st_v.params["w"]),
+        np.testing.assert_array_equal(np.asarray(bstate["theta_g"]["w"]),
                                       np.asarray(st_s.params["w"]))
     else:
-        np.testing.assert_allclose(np.asarray(st_v.params["w"]),
+        np.testing.assert_allclose(np.asarray(bstate["theta_g"]["w"]),
                                    np.asarray(st_s.params["w"]),
                                    atol=1e-7, rtol=0)
 
