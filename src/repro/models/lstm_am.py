@@ -54,21 +54,25 @@ class LstmAM:
         state freezes at each row's length and the backward direction of a
         biLSTM starts at the last valid frame, so batched outputs match
         per-utterance runs on the valid region (see recurrent.lstm_apply).
+        The bidirectional stack runs time-major: one transpose in, one
+        out, and each layer's backward direction is a reverse scan over
+        the same input (recurrent.bilstm_apply).
         """
+        if self.bidirectional:
+            xs = feats.transpose(1, 0, 2)
+            mask = None if lens is None else recurrent.time_mask(
+                lens, xs.shape[0])
+            for i in range(self.n_layers):
+                xs = recurrent.bilstm_apply(params[f"l{i}"]["fwd"],
+                                            params[f"l{i}"]["bwd"], xs, mask)
+            return xs.transpose(1, 0, 2), {"state": None}
         x = feats
         new_state = []
         for i in range(self.n_layers):
-            if self.bidirectional:
-                x = recurrent.bilstm_apply(params[f"l{i}"]["fwd"],
-                                           params[f"l{i}"]["bwd"], x,
-                                           lens=lens)
-                new_state.append(None)
-            else:
-                st = None if state is None else state[i]
-                x, st = recurrent.lstm_apply(params[f"l{i}"], x, st,
-                                             lens=lens)
-                new_state.append(st)
-        return x, {"state": new_state if not self.bidirectional else None}
+            st = None if state is None else state[i]
+            x, st = recurrent.lstm_apply(params[f"l{i}"], x, st, lens=lens)
+            new_state.append(st)
+        return x, {"state": new_state}
 
     def unembed_matrix(self, params):
         return params["out"]

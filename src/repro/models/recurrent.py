@@ -35,30 +35,34 @@ def lstm_cell(params, x_t, h, c):
     return h.astype(x_t.dtype), c
 
 
-def lstm_apply(params, x, state=None, lens=None):
-    """x (B,S,D) -> (B,S,H). state: optional (h, c) carried (chunked BPTT).
+def time_mask(lens, s: int):
+    """lens (B,) -> the time-major (S,B,1) mask of each row's valid frames."""
+    return (jnp.arange(s)[None, :] < lens[:, None]).T[..., None]
 
-    lens (B,) optional valid lengths: the carried (h, c) freezes once a
-    row passes its length, so a padded batch hands back exactly the state
-    an unpadded per-row run would (the serving engine's batching
-    invariant).  Outputs past a row's length are unspecified — callers
-    mask or slice them.
-    """
-    b = x.shape[0]
+
+def lstm_zero_state(params, b: int, dtype):
+    """The (h, c) a row starts from: h in the activations' dtype, c f32."""
     d_h = params["wh"].shape[0]
-    if state is None:
-        state = (jnp.zeros((b, d_h), x.dtype), jnp.zeros((b, d_h), jnp.float32))
+    return (jnp.zeros((b, d_h), dtype), jnp.zeros((b, d_h), jnp.float32))
 
-    if lens is None:
+
+def lstm_scan(params, xs, state, mask=None, reverse=False):
+    """Time-major LSTM: xs (S,B,D), state (h, c) -> (ys (S,B,H), (h, c)).
+
+    mask (S,B,1) optional: (h, c) holds wherever it is False, so a row's
+    state stays what it was before its first valid frame and after its
+    last.  With ``reverse`` the scan runs from the last frame to the first
+    and writes each output at its own frame.  Outputs where the mask is
+    False are unspecified — callers mask or slice them.
+    """
+    if mask is None:
         def step(carry, x_t):
             h, c = carry
             h, c = lstm_cell(params, x_t, h, c)
             return (h, c), h
 
-        (h, c), ys = jax.lax.scan(step, state, x.transpose(1, 0, 2))
-        return ys.transpose(1, 0, 2), (h, c)
-
-    mask = (jnp.arange(x.shape[1])[None, :] < lens[:, None])   # (B,S)
+        (h, c), ys = jax.lax.scan(step, state, xs, reverse=reverse)
+        return ys, (h, c)
 
     def step(carry, xm):
         x_t, m_t = xm
@@ -68,39 +72,40 @@ def lstm_apply(params, x, state=None, lens=None):
         c = jnp.where(m_t, c2, c)
         return (h, c), h2
 
-    (h, c), ys = jax.lax.scan(
-        step, state, (x.transpose(1, 0, 2), mask.T[..., None]))
+    (h, c), ys = jax.lax.scan(step, state, (xs, mask), reverse=reverse)
+    return ys, (h, c)
+
+
+def lstm_apply(params, x, state=None, lens=None):
+    """x (B,S,D) -> (B,S,H). state: optional (h, c) carried (chunked BPTT).
+
+    lens (B,) optional valid lengths: the carried (h, c) freezes once a
+    row passes its length, so a padded batch hands back exactly the state
+    an unpadded per-row run would (the serving engine's batching
+    invariant).  Outputs past a row's length are unspecified — callers
+    mask or slice them.
+    """
+    if state is None:
+        state = lstm_zero_state(params, x.shape[0], x.dtype)
+    mask = None if lens is None else time_mask(lens, x.shape[1])
+    ys, (h, c) = lstm_scan(params, x.transpose(1, 0, 2), state, mask)
     return ys.transpose(1, 0, 2), (h, c)
 
 
-def masked_reverse(x, lens):
-    """Reverse each row's first lens[b] steps along time; zero the tail.
+def bilstm_apply(fwd_params, bwd_params, xs, mask=None):
+    """One time-major biLSTM layer: xs (S,B,D) -> (S,B,2H), the forward
+    and backward directions' outputs side by side.
 
-    x (B,S,...), lens (B,) -> same shape.  Involution on the valid region:
-    applying it twice restores the input (used to run the backward LSTM of
-    a biLSTM over ragged batches without reading padding).
+    The backward direction is a reverse scan over the same input.  With
+    mask (S,B,1) the state holds at zero through a row's padded tail, so
+    the backward pass starts at each row's last *valid* frame and padded
+    batches match per-row runs on the valid region (positions past a
+    row's length are unspecified).
     """
-    s = x.shape[1]
-    ar = jnp.arange(s)
-    idx = jnp.clip(lens[:, None] - 1 - ar[None, :], 0, s - 1)   # (B,S)
-    idx = idx.reshape(idx.shape + (1,) * (x.ndim - 2))
-    rev = jnp.take_along_axis(x, idx, axis=1)
-    mask = (ar[None, :] < lens[:, None]).reshape(
-        x.shape[:2] + (1,) * (x.ndim - 2))
-    return jnp.where(mask, rev, jnp.zeros((), x.dtype))
-
-
-def bilstm_apply(fwd_params, bwd_params, x, lens=None):
-    """Bidirectional LSTM.  With lens, the backward pass starts at each
-    row's last *valid* frame, so padded batches match per-row runs on the
-    valid region (positions past lens are unspecified)."""
-    if lens is None:
-        yf, _ = lstm_apply(fwd_params, x)
-        yb, _ = lstm_apply(bwd_params, x[:, ::-1])
-        return jnp.concatenate([yf, yb[:, ::-1]], axis=-1)
-    yf, _ = lstm_apply(fwd_params, x, lens=lens)
-    yb, _ = lstm_apply(bwd_params, masked_reverse(x, lens), lens=lens)
-    return jnp.concatenate([yf, masked_reverse(yb, lens)], axis=-1)
+    state = lstm_zero_state(fwd_params, xs.shape[1], xs.dtype)
+    yf, _ = lstm_scan(fwd_params, xs, state, mask)
+    yb, _ = lstm_scan(bwd_params, xs, state, mask, reverse=True)
+    return jnp.concatenate([yf, yb], axis=-1)
 
 
 # ================================================================= RG-LRU

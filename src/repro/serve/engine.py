@@ -19,8 +19,9 @@ inference-as-a-service):
 
 Length correctness is delegated to the model's ``lens`` support
 (``models/recurrent.py``): padded rows freeze their recurrent state at
-their true length and the biLSTM backward pass starts at the last valid
-frame, so batched == sequential to fp tolerance (pinned by
+their true length, and the biLSTM's backward direction, a reverse scan
+whose state holds at zero through each row's padded tail, starts at the
+last valid frame, so batched == sequential to fp tolerance (pinned by
 tests/test_serve_engine.py).
 
 Top-k emission reuses ``kernels/topk_logits`` (the Pallas selection

@@ -136,6 +136,35 @@ def test_swa_attention(spec):
         spec((1, 2, 4096, 128))), "swa_attention_tiles")
 
 
+def test_teacher_bilstm_writes_aligned(spec):
+    """The biLSTM teacher's forward with its head and top-k emitter, at
+    128 x 64 frames x 192 features: every one of the ten scans (five
+    layers, two directions) stacks its outputs time-major, so each step's
+    write is a whole aligned tile, and no gather reverses a direction."""
+    from repro.core.ssl_pipeline import am_configs
+    from repro.models import build_model
+    from repro.serve import StreamingEngine
+    _, cfg = am_configs(n_layers=5, lstm_hidden=768, n_senones=3183,
+                        feat_dim=192)
+    params = jax.eval_shape(
+        lambda: build_model(cfg).init(jax.random.key(0)))
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    engine = StreamingEngine(cfg, params, k=20, topk_impl="kernel",
+                             interpret=False)
+    b, s = 128, 64
+    txt = engine._fwd_dict.lower(
+        engine.params, {"feats": spec((b, s, 192)), "mask": spec((b, s))}
+    ).compile().as_text()
+    outs = [m.group(1) for ln in txt.splitlines()
+            if re.match(r"\s*%while[.\d]* = \(", ln)
+            for m in re.finditer(rf"bf16\[{s},{b},768\]\{{([\d,]+)", ln)]
+    assert len(outs) == 10 and set(outs) == {"2,1,0"}, outs
+    aligned = re.findall(r"dynamic-update-slice\(.*?\"is_index_aligned\":"
+                         r"\[(\w+)", txt)
+    assert len(aligned) >= 10 and "false" not in aligned, aligned
+    assert txt.count("gather(") <= 1, txt.count("gather(")
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
                          ids=["gate-up", "down"])
 def test_moe_gmm_expert_widths(spec, k, n):
